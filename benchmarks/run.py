@@ -52,6 +52,9 @@ def main() -> int:
     ap.add_argument("--out", default="artifacts/bench/results")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import common as C
     from benchmarks import tables as T
 

@@ -111,12 +111,14 @@ def lint_attention(cfg, policy, attn=None) -> list:
         try:
             import jax
 
-            if jax.default_backend() != "tpu":
-                reasons.append(
-                    "no TPU present — kernel bodies run under the "
-                    "Pallas interpreter (correct but reference-speed)")
-        except Exception:  # symbolic/lint-only environments
-            pass
+            platform = jax.default_backend()
+        except (ImportError, RuntimeError):  # no JAX backend to ask
+            platform = None
+        if platform not in (None, "tpu"):
+            reasons.append(
+                f"no TPU present (JAX's backend is {platform!r}) — kernel "
+                "bodies run under the Pallas interpreter (correct but "
+                "reference-speed)")
         for reason in reasons:
             diags.append(Diagnostic(
                 code="QL602", site="*/attn",

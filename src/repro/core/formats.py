@@ -122,10 +122,10 @@ class FloatFormat:
         # ldexp, not exp2: XLA's f32 exp2 is an approximation (exp2(13) ->
         # 8192.004 on CPU), which would put outputs slightly OFF the
         # representable grid for large-exponent formats (e5m2).
-        quantum = jnp.ldexp(
-            jnp.asarray(1.0, jnp.float32),
-            (e - self.man_bits).astype(jnp.int32),
-        )
+        # a full plane of ones, not a scalar: Mosaic cannot bitcast a
+        # scalar inside a Pallas kernel
+        quantum = jnp.ldexp(jnp.ones_like(xf),
+                            (e - self.man_bits).astype(jnp.int32))
         q = jnp.round(xf / quantum) * quantum  # round-half-even
         # Re-check: rounding up can bump the exponent (e.g. 1.96 -> 2.0); that
         # is still representable because the mantissa wraps to 0 at e+1.

@@ -53,32 +53,35 @@ def dequantize(codes: jnp.ndarray, scale: jnp.ndarray, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 # 4-bit code packing (compressed weight storage)
 # ---------------------------------------------------------------------------
-def pack_int4_codes(codes: jnp.ndarray) -> jnp.ndarray:
-    """Pack signed 4-bit codes two-per-byte along the last dim (even length).
+def pack_int4_codes(codes: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Pack signed 4-bit codes two-per-byte along ``axis`` (even length).
 
-    Element ``2i`` lands in the low nibble, ``2i+1`` in the high nibble; each
-    nibble is the code's 4-bit two's complement.  Inverse of
+    The first half of ``axis`` lands in the low nibbles, the second half in
+    the high nibbles; each nibble is the code's 4-bit two's complement.
+    Unpacking is then elementwise plus one concatenation, with no
+    interleave for the compiler to relayout.  Inverse of
     ``unpack_int4_codes``.
     """
-    if codes.shape[-1] % 2:
+    if codes.shape[axis] % 2:
+        name = "last dim" if axis % codes.ndim == codes.ndim - 1 else \
+            f"dim {axis}"
         raise ValueError(
-            f"pack_int4_codes needs an even last dim, got {codes.shape}"
+            f"pack_int4_codes needs an even {name}, got {codes.shape}"
         )
-    c = codes.astype(jnp.int32)
-    lo = c[..., 0::2] & 0xF
-    hi = c[..., 1::2] & 0xF
-    return (lo | (hi << 4)).astype(jnp.uint8)
+    lo, hi = jnp.split(codes.astype(jnp.int32), 2, axis=axis)
+    return ((lo & 0xF) | ((hi & 0xF) << 4)).astype(jnp.uint8)
 
 
-def unpack_int4_codes(packed: jnp.ndarray) -> jnp.ndarray:
-    """uint8 nibble pairs -> int8 codes; last dim doubles."""
-    p = packed.astype(jnp.int32)
-    lo = p & 0xF
-    hi = (p >> 4) & 0xF
-    c = jnp.stack([lo, hi], axis=-1).reshape(
-        *p.shape[:-1], p.shape[-1] * 2
-    )
-    return jnp.where(c >= 8, c - 16, c).astype(jnp.int8)
+def int4_nibbles(packed: jnp.ndarray):
+    """uint8 nibble pairs -> ``(low, high)`` int8 codes, elementwise."""
+    b = jax.lax.bitcast_convert_type(packed, jnp.int8)
+    # arithmetic shifts sign-extend each nibble's two's complement
+    return (b << 4) >> 4, b >> 4
+
+
+def unpack_int4_codes(packed: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """uint8 nibble pairs -> int8 codes; dim ``axis`` doubles."""
+    return jnp.concatenate(int4_nibbles(packed), axis=axis)
 
 
 # ---------------------------------------------------------------------------

@@ -43,7 +43,7 @@ from repro.core import abfp as abfp_mod
 from repro.core.calibration import Calibrator
 from repro.core.formats import IntFormat
 from repro.core.policy import Policy, QuantPolicy, TensorQuant, resolve_policy
-from repro.core.quantize import maybe_ste, unpack_int4_codes
+from repro.core.quantize import maybe_ste
 
 
 def _dynamic_max_alpha(x: jnp.ndarray) -> jnp.ndarray:
@@ -170,17 +170,15 @@ def _compressed_group_matmul(x, wk, policy: QuantPolicy, *, site: str,
     materializing the kernel) — the same documented
     equivalent-not-bit-identical deviation the int8 backend has.
     """
-    codes = wk.codes
-    if wk.packed:
-        codes = unpack_int4_codes(codes)
-    if codes.ndim != 3:
+    if wk.codes.ndim != 2:
         raise ValueError(
-            "compressed backend expects rank-3 (N, G, n) codes at apply "
-            f"time, got {codes.shape} (stacked kernels are sliced per "
+            "compressed backend expects rank-2 (Kp, N) codes at apply "
+            f"time, got {wk.codes.shape} (stacked kernels are sliced per "
             "layer by scan before they reach qmatmul)"
         )
-    ws = wk.scale.astype(jnp.float32)  # (N, G)
-    N, G, n = codes.shape
+    ws = wk.scale.astype(jnp.float32)  # (G, N)
+    G, N = ws.shape
+    n = wk.group
     tq = policy.input
 
     if (tq is not None and isinstance(tq.fmt, IntFormat)
@@ -190,11 +188,19 @@ def _compressed_group_matmul(x, wk, policy: QuantPolicy, *, site: str,
             x, tq.fmt, axis=-1, n=n,
             scale_dtype=jnp.dtype(tq.scale_dtype),
         )
-        partial = jnp.einsum(
-            "...gk,ngk->...gn", xc, codes, preferred_element_type=jnp.int32
-        )
+        if wk.packed:
+            # each nibble plane meets its half of every group, exact in
+            # int32, so the unpacked codes are never assembled
+            lo, hi = wk.nibbles()
+            partial = sum(
+                jnp.einsum("...gk,gkn->...gn", xh, ch,
+                           preferred_element_type=jnp.int32)
+                for xh, ch in zip(jnp.split(xc, 2, axis=-1), (lo, hi)))
+        else:
+            partial = jnp.einsum("...gk,gkn->...gn", xc, wk.grouped_codes(),
+                                 preferred_element_type=jnp.int32)
         return jnp.einsum(
-            "...gn,...g,ng->...n", partial.astype(jnp.float32),
+            "...gn,...g,gn->...n", partial.astype(jnp.float32),
             xs.astype(jnp.float32), ws,
         )
 
@@ -204,8 +210,9 @@ def _compressed_group_matmul(x, wk, policy: QuantPolicy, *, site: str,
     if wk.pad:
         xq = jnp.pad(xq, [(0, 0)] * (xq.ndim - 1) + [(0, wk.pad)])
     xg = xq.reshape(*xq.shape[:-1], G, n)
-    partial = jnp.einsum("...gk,ngk->...gn", xg, codes.astype(jnp.float32))
-    return jnp.einsum("...gn,ng->...n", partial, ws)
+    partial = jnp.einsum("...gk,gkn->...gn", xg,
+                         wk.grouped_codes().astype(jnp.float32))
+    return jnp.einsum("...gn,gn->...n", partial, ws)
 
 
 # ---------------------------------------------------------------------------

@@ -20,29 +20,58 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import Format, IntFormat
 
 
-def _qdq_tile(xg: jnp.ndarray, fmt: Format, scale_dtype) -> jnp.ndarray:
-    """QDQ a (BM, G, n) group-tiled f32 block against per-group max."""
-    alpha = jnp.max(jnp.abs(xg), axis=-1, keepdims=True)
-    # bf16 scales, round-to-nearest (matches core/abfp + ref oracles)
-    a16 = alpha.astype(scale_dtype)
-    alpha = jnp.maximum(a16.astype(jnp.float32), 1e-12)
-    scale = alpha / fmt.qmax_pos
+def group_amax(a: jnp.ndarray, n: int, axis: int = -1) -> jnp.ndarray:
+    """Max of ``|a|`` over each aligned run of ``n`` elements along ``axis``,
+    broadcast back to every element of the run (2-D tiles).
+
+    Mosaic refuses to split the lane dim into ``(groups, n)`` (an
+    unsupported shape cast), so the group max is a doubling scan of
+    ``pltpu.roll`` shifts instead: after the steps with shifts up to s,
+    every element holds the max over its group members within distance
+    2s - 1, and ceil(log2 n) steps cover the group.  ``pltpu.roll`` moves
+    element i to i + shift, like ``jnp.roll``, so an element's neighbour
+    under a shift of s sits in its group when its offset in the group is at
+    least s, and under L - s (that is, -s) when the offset is below n - s.
+    A max is exact, so the result equals a reshape-and-reduce bit for bit.
+    """
+    axis = axis % a.ndim
+    L = a.shape[axis]
+    a = jnp.abs(a)
+    pos = jax.lax.broadcasted_iota(jnp.int32, a.shape, axis) % n
+    s = 1
+    while s < n:
+        for shift, same in ((s, pos >= s), (L - s, pos < n - s)):
+            a = jnp.maximum(a, jnp.where(same, pltpu.roll(a, shift, axis), a))
+        s *= 2
+    return a
+
+
+def group_scale(v: jnp.ndarray, n: int, axis: int, qmax: float,
+                scale_dtype=jnp.bfloat16) -> jnp.ndarray:
+    """Per-element ABFP step size: the group max rounded to ``scale_dtype``
+    (round-to-nearest, as in core/abfp and the ref oracles) over ``qmax``."""
+    alpha = group_amax(v, n, axis).astype(scale_dtype).astype(jnp.float32)
+    return jnp.maximum(alpha, 1e-12) / qmax
+
+
+def _qdq_tile(x: jnp.ndarray, fmt: Format, scale_dtype, n: int,
+              axis: int = -1) -> jnp.ndarray:
+    """QDQ a 2-D f32 tile in groups of ``n`` along ``axis``."""
+    scale = group_scale(x, n, axis, fmt.qmax_pos, scale_dtype)
     if isinstance(fmt, IntFormat):
-        q = jnp.clip(jnp.round(xg / scale), fmt.qmin, fmt.qmax_pos)
+        q = jnp.clip(jnp.round(x / scale), fmt.qmin, fmt.qmax_pos)
         return q * scale
-    return fmt.qdq_unit(xg / scale) * scale
+    return fmt.qdq_unit(x / scale) * scale
 
 
 def _kernel(x_ref, o_ref, *, n: int, fmt: Format, scale_dtype):
     x = x_ref[...].astype(jnp.float32)
-    bm, bk = x.shape
-    xg = x.reshape(bm, bk // n, n)
-    y = _qdq_tile(xg, fmt, scale_dtype)
-    o_ref[...] = y.reshape(bm, bk).astype(o_ref.dtype)
+    o_ref[...] = _qdq_tile(x, fmt, scale_dtype, n).astype(o_ref.dtype)
 
 
 @functools.partial(

@@ -57,6 +57,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.messages import abfp_group_message, attention_block_message
+from repro.kernels.abfp_qdq import group_scale
 
 NEG_INF = -1e9  # mask value — matches nn.attention.NEG_INF (finite in bf16)
 M_INIT = -1e30  # running-max init; exp(M_INIT - m_new) underflows to exact 0
@@ -86,14 +87,8 @@ def _probs_qdq(p, *, n: int, qmax: float, qmin: float):
     to a multiple of n so groups here line up with the reference's
     zero-padded groups.
     """
-    bq, bk = p.shape
-    pg = p.reshape(bq, bk // n, n)
-    alpha = jnp.max(jnp.abs(pg), axis=-1, keepdims=True)
-    a16 = alpha.astype(jnp.bfloat16)  # paper: scales live in BF16
-    alpha = jnp.maximum(a16.astype(jnp.float32), 1e-12)
-    scale = alpha / qmax
-    q = jnp.clip(jnp.round(pg / scale), qmin, qmax)
-    return (q * scale).reshape(bq, bk)
+    scale = group_scale(p, n, -1, qmax)  # paper: scales live in BF16
+    return jnp.clip(jnp.round(p / scale), qmin, qmax) * scale
 
 
 def _scores(q, kc_ref, ks_ref, qp_ref, kp_ref, win_ref, *, scale: float,

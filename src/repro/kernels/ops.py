@@ -185,18 +185,15 @@ def abfp_matmul_fused(x, w, policy: QuantPolicy,
 def quant_matmul_fused(x, wk, tq_x, interpret: bool | None = None):
     """Compressed-domain Pallas dispatch: (…, K) x stored codes + scales.
 
-    ``wk`` is a ``CompressedKernel``; packed INT4 codes are unpacked here
+    ``wk`` is a ``CompressedKernel``, whose ``(Kp, N)`` codes and ``(G, N)``
+    scales the kernel reads as stored; packed INT4 codes are unpacked here
     (the Pallas kernel consumes plain int8 codes).  x is zero-padded to
     the stored (padded) contraction length so codes and activations tile
     identically.
     """
-    from repro.core.quantize import unpack_int4_codes
-
     interpret = should_interpret() if interpret is None else interpret
-    codes, scales = wk.codes, wk.scale
-    if wk.packed:
-        codes = unpack_int4_codes(codes)
-    N, G, n = codes.shape
+    codes, scales, n = wk.int8_codes(), wk.scale, wk.group
+    N = codes.shape[-1]
     shape = x.shape
     x2 = x.reshape(-1, shape[-1]).astype(jnp.float32)
     if wk.pad:

@@ -11,7 +11,7 @@ Variants:
   * ``abfp_matmul_int8`` — beyond-paper: per-group int8 codes contracted
     with int32 accumulation (2x MXU throughput on TPU), rescaled per group.
   * ``quant_matmul``     — compressed-domain serving: the weight arrives as
-    PRE-QUANTIZED int8 codes (N, G, n) + per-group unit scales (N, G); only
+    PRE-QUANTIZED int8 codes (K, N) + per-group unit scales (G, N); only
     x is quantized in-kernel.  HBM reads the codes, never a dequantized
     kernel — the ``compressed`` execution backend's fast path.
 
@@ -30,17 +30,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import Format, IntFormat
-from repro.kernels.abfp_qdq import _qdq_tile
+from repro.kernels.abfp_qdq import _qdq_tile, group_scale
 
 
-def _scales_tile(v: jnp.ndarray, n: int, axis: int) -> jnp.ndarray:
-    """Per-group bf16-rounded scales for a 2-D tile along ``axis``."""
-    vm = jnp.moveaxis(v, axis, -1)
-    g = vm.shape[-1] // n
-    vg = vm.reshape(*vm.shape[:-1], g, n)
-    alpha = jnp.max(jnp.abs(vg), axis=-1)
-    a32 = alpha.astype(jnp.bfloat16).astype(jnp.float32)
-    return jnp.maximum(a32, 1e-12)
+def _grouped_int_dot(xc, sx, wc, sw_row, *, n: int):
+    """``sum_g (xc_g . wc_g) * sx_g * sw_g`` with an int8 x int8 -> int32
+    contraction per ABFP group, rescaled in f32 and summed in group order.
+
+    ``xc``: (bm, bk) integer-valued f32 x codes; ``sx``: (bm, bk) their
+    per-element step sizes (uniform inside a group); ``wc``: (bk, bn) int8
+    weight codes; ``sw_row(g)``: the (1, bn) weight step sizes of group g.
+    Mosaic cannot batch a dot over a middle group axis, so each group's
+    lanes of x are contracted with its rows of ``wc`` — exact in int32.
+    """
+    xi = xc.astype(jnp.int8)
+    total = None
+    for g in range(xc.shape[1] // n):
+        cols = slice(g * n, (g + 1) * n)
+        p = jax.lax.dot_general(xi[:, cols], wc[cols, :],
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.int32)
+        part = p.astype(jnp.float32) * sx[:, g * n:g * n + 1] * sw_row(g)
+        total = part if total is None else total + part
+    return total
+
+
+def _int_codes(v, scale, fmt):
+    return jnp.clip(jnp.round(v / scale), fmt.qmin, fmt.qmax_pos)
 
 
 def _fp_kernel(x_ref, w_ref, o_ref, acc_ref, *, n, fmt_x, fmt_w, k_steps):
@@ -50,15 +66,10 @@ def _fp_kernel(x_ref, w_ref, o_ref, acc_ref, *, n, fmt_x, fmt_w, k_steps):
 
     x = x_ref[...].astype(jnp.float32)  # (bm, bk)
     w = w_ref[...].astype(jnp.float32)  # (bk, bn)
-    bm, bk = x.shape
-    bn = w.shape[1]
-    xq = _qdq_tile(x.reshape(bm, bk // n, n), fmt_x,
-                   jnp.bfloat16).reshape(bm, bk)
-    wq = _qdq_tile(
-        jnp.moveaxis(w, 0, 1).reshape(bn, bk // n, n), fmt_w, jnp.bfloat16
-    ).reshape(bn, bk)
+    xq = _qdq_tile(x, fmt_x, jnp.bfloat16, n, axis=-1)
+    wq = _qdq_tile(w, fmt_w, jnp.bfloat16, n, axis=0)
     acc_ref[...] += jax.lax.dot_general(
-        xq, wq, (((1,), (1,)), ((), ())),
+        xq, wq, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -74,28 +85,12 @@ def _int8_kernel(x_ref, w_ref, o_ref, acc_ref, *, n, fmt_x, fmt_w, k_steps):
 
     x = x_ref[...].astype(jnp.float32)  # (bm, bk)
     w = w_ref[...].astype(jnp.float32)  # (bk, bn)
-    bm, bk = x.shape
-    bn = w.shape[1]
-    g = bk // n
-    sx = _scales_tile(x, n, -1) / fmt_x.qmax_pos  # (bm, g)
-    sw = _scales_tile(w, n, 0) / fmt_w.qmax_pos  # (bn, g)
-    xg = x.reshape(bm, g, n)
-    wg = jnp.moveaxis(w, 0, 1).reshape(bn, g, n)
-    xc = jnp.clip(jnp.round(xg / sx[..., None]), fmt_x.qmin,
-                  fmt_x.qmax_pos).astype(jnp.int8)
-    wc = jnp.clip(jnp.round(wg / sw[..., None]), fmt_w.qmin,
-                  fmt_w.qmax_pos).astype(jnp.int8)
-    # Per-group int8 x int8 -> int32 contraction (MXU native), then rescale.
-    partial = jax.lax.dot_general(
-        xc, wc, (((2,), (2,)), ((1,), (1,))),
-        preferred_element_type=jnp.int32,
-    )  # (g, bm, bn)
-    scaled = (
-        partial.astype(jnp.float32)
-        * jnp.moveaxis(sx, 1, 0)[:, :, None]
-        * jnp.moveaxis(sw, 1, 0)[:, None, :]
-    )
-    acc_ref[...] += scaled.sum(axis=0)
+    sx = group_scale(x, n, -1, fmt_x.qmax_pos)  # (bm, bk)
+    sw = group_scale(w, n, 0, fmt_w.qmax_pos)  # (bk, bn)
+    wc = _int_codes(w, sw, fmt_w).astype(jnp.int8)
+    acc_ref[...] += _grouped_int_dot(
+        _int_codes(x, sx, fmt_x), sx, wc,
+        lambda g: sw[g * n:g * n + 1, :], n=n)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _done():
@@ -188,32 +183,22 @@ def abfp_matmul_int8(
 def _stored_codes_kernel(x_ref, wc_ref, ws_ref, o_ref, acc_ref, *,
                          n, fmt_x, k_steps):
     """x is quantized in-VMEM; the weight arrives as codes + unit scales."""
-    @pl.when(pl.program_id(2) == 0)
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)   # (bm, bk)
-    wc = wc_ref[...]                      # (bn, g, n) int8 codes
-    ws = ws_ref[...].astype(jnp.float32)  # (bn, g) unit scales
-    bm, bk = x.shape
-    g = bk // n
-    sx = _scales_tile(x, n, -1) / fmt_x.qmax_pos  # (bm, g)
-    xg = x.reshape(bm, g, n)
-    xc = jnp.clip(jnp.round(xg / sx[..., None]), fmt_x.qmin,
-                  fmt_x.qmax_pos).astype(jnp.int8)
-    # Per-group int8 x stored-int8 -> int32 contraction, then rescale.
-    partial = jax.lax.dot_general(
-        xc, wc, (((2,), (2,)), ((1,), (1,))),
-        preferred_element_type=jnp.int32,
-    )  # (g, bm, bn)
-    scaled = (
-        partial.astype(jnp.float32)
-        * jnp.moveaxis(sx, 1, 0)[:, :, None]
-        * jnp.moveaxis(ws, 1, 0)[:, None, :]
-    )
-    acc_ref[...] += scaled.sum(axis=0)
+    wc = wc_ref[...]                      # (bk, bn) int8 codes
+    gk = x.shape[1] // n
+    sx = group_scale(x, n, -1, fmt_x.qmax_pos)  # (bm, bk)
+    # ws_ref holds every group's (1, bn) scale row of this N-block
+    acc_ref[...] += _grouped_int_dot(
+        _int_codes(x, sx, fmt_x), sx, wc,
+        lambda g: ws_ref[pl.ds(k * gk + g, 1), :].astype(jnp.float32), n=n)
 
-    @pl.when(pl.program_id(2) == k_steps - 1)
+    @pl.when(k == k_steps - 1)
     def _done():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -230,48 +215,44 @@ def quant_matmul(
 ) -> jnp.ndarray:
     """Compressed-domain matmul: ``x (M, K)`` vs stored weight codes.
 
-    ``w_codes``: (N, G, n) int8 pre-quantized codes (contraction grouped
-    last, G*n == K); ``w_scales``: (N, G) f32 unit scales.  Only x is
+    ``w_codes``: (K, N) int8 pre-quantized codes, group g in rows
+    ``[g*n, (g+1)*n)``; ``w_scales``: (G, N) f32 unit scales, G*n == K —
+    ``CompressedKernel``'s stored layout, read as is.  Only x is
     quantized (in VMEM, against ``fmt_x``); the contraction is int8 x int8
     with int32 accumulation and per-group rescale, so the dense kernel is
     never materialized anywhere — HBM traffic for weights is the codes.
     """
     M, K = x.shape
-    if w_codes.ndim != 3:
+    if w_codes.ndim != 2:
         raise ValueError(
-            f"w_codes must be (N, G, n) grouped codes, got {w_codes.shape}"
+            f"w_codes must be (K, N) codes, got {w_codes.shape}"
         )
-    N, G, n2 = w_codes.shape
-    if n2 != n:
-        raise ValueError(
-            f"stored group length {n2} (w_codes.shape={w_codes.shape}) "
-            f"!= requested n={n}"
-        )
-    if G * n != K:
-        raise ValueError(
-            f"stored codes cover K={G * n} (G={G}, n={n}) but x has K={K}"
-        )
-    if w_scales.shape != (N, G):
-        raise ValueError(
-            f"w_scales shape {w_scales.shape} != (N, G)=({N}, {G})"
-        )
+    K2, N = w_codes.shape
+    if K2 != K:
+        raise ValueError(f"w_codes cover K={K2} but x has K={K}")
     bm = min(block_m, M)
     bn = min(block_n, N)
     bk = min(block_k, K)
     bk -= bk % n
     bk = max(bk, min(n, K))
     _check_blocking(M, N, K, bm, bn, bk, n)
+    G = K // n
+    if w_scales.shape != (G, N):
+        raise ValueError(
+            f"w_scales shape {w_scales.shape} != (G, N)=({G}, {N})"
+        )
     k_steps = K // bk
-    gk = bk // n
     grid = (M // bm, N // bn, k_steps)
+    # the scales enter whole along G: the Mosaic lowering only accepts
+    # blocks whose last two dims are (8, 128)-aligned or whole
     return pl.pallas_call(
         functools.partial(_stored_codes_kernel, n=n, fmt_x=fmt_x,
                           k_steps=k_steps),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, gk, n), lambda i, j, k: (j, k, 0)),
-            pl.BlockSpec((bn, gk), lambda i, j, k: (j, k)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((G, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),

@@ -99,4 +99,66 @@ def int8_matmul_ref(x: jnp.ndarray, w: jnp.ndarray, fmt_x: Format,
     xc = jnp.clip(jnp.round(xg / sx[..., None]), fmt_x.qmin, fmt_x.qmax_pos)
     wc = jnp.clip(jnp.round(wg / sw[..., None]), fmt_w.qmin, fmt_w.qmax_pos)
     partial = jnp.einsum("mgk,ngk->mgn", xc, wc)  # int-valued f32
-    return jnp.einsum("mgn,mg,ng->mn", partial, sx, jnp.moveaxis(sw, 0, 0))
+    # the rescale is f32 arithmetic, not an MXU product: keep it exact
+    return jnp.einsum("mgn,mg,ng->mn", partial, sx, jnp.moveaxis(sw, 0, 0),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def quant_matmul_ref(x: jnp.ndarray, w_codes: jnp.ndarray,
+                     w_scales: jnp.ndarray, fmt_x: IntFormat,
+                     n: int = 64) -> jnp.ndarray:
+    """Reference stored-codes matmul: x quantized to per-group int codes,
+    contracted against ``w_codes`` (K, N) group by group (rows
+    ``[g*n, (g+1)*n)``), each group's integer sum rescaled by ``x``'s step
+    and ``w_scales`` (G, N)."""
+    M, K = x.shape
+    K2, N = w_codes.shape
+    G = K // n
+    if K2 != K or G * n != K:
+        raise ValueError(
+            f"codes {w_codes.shape} do not cover x's K={K} in groups of "
+            f"n={n}")
+    sx = _group_scales(x, -1, n) / fmt_x.qmax_pos  # (M, G)
+    xg = x.astype(jnp.float32).reshape(M, G, n)
+    xc = jnp.clip(jnp.round(xg / sx[..., None]), fmt_x.qmin, fmt_x.qmax_pos)
+    partial = jnp.einsum("mgk,gkn->mgn", xc,
+                         w_codes.astype(jnp.float32).reshape(G, n, N))
+    return jnp.einsum("mgn,mg,gn->mn", partial, sx,
+                      w_scales.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def flash_attention_quant_ref(qh, k_codes, v_codes, k_scale, v_scale,
+                              q_pos, kv_pos, *, causal: bool = True,
+                              window=None, probs_fmt: Format | None = None,
+                              probs_n: int = 0) -> jnp.ndarray:
+    """Reference attention over quantized KV: dequantize the codes, mask by
+    absolute positions (``kv_pos < 0`` = invalid), softmax with the finite
+    -1e9 mask, optional ABFP QDQ of the probabilities, then P·V.
+
+    Shapes as ``kernels.ops.flash_attention_quant_gqa``: ``qh`` (B, S, H,
+    D), codes (B, T, KV, D), scales (B, T, KV), ``q_pos`` (B, S),
+    ``kv_pos`` (B, T).
+    """
+    B, S, H, D = qh.shape
+    KV = k_codes.shape[2]
+    kh = k_codes.astype(jnp.float32) * k_scale[..., None]
+    vh = v_codes.astype(jnp.float32) * v_scale[..., None]
+    qg = qh.astype(jnp.float32).reshape(B, S, KV, H // KV, D)
+    s = jnp.einsum("bskgd,btkd->bkgst", qg, kh) * D**-0.5
+    qp = q_pos[:, :, None]
+    kp = kv_pos[:, None, :]
+    m = kp >= 0
+    if causal:
+        m = m & (kp <= qp)
+    if window is not None:
+        m = m & (kp > qp - window)
+    s = jnp.where(m[:, None, None], s, -1e9)
+    p = jax.nn.softmax(s, axis=-1)
+    if probs_n:
+        T = p.shape[-1]
+        pad = -T % probs_n
+        pp = jnp.pad(p, [(0, 0)] * 4 + [(0, pad)])
+        p = abfp_qdq_ref(pp, probs_fmt, probs_n)[..., :T]
+    out = jnp.einsum("bkgst,btkd->bskgd", p, vh)
+    return out.reshape(B, S, H, D).astype(qh.dtype)

@@ -1,12 +1,16 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # placeholders only: never claim a chip
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell with
 ShapeDtypeStruct inputs (zero allocation), then record memory analysis, cost
 analysis and the collective schedule for the roofline report.
 
-The two lines above MUST stay first: jax locks the device count on first
-init, and the production mesh needs 512 host-platform placeholder devices.
+The lines above MUST stay first: jax locks the platform and the device
+count on first init.  The production mesh needs 512 host-platform
+placeholder devices, and pinning the CPU platform keeps a dry-run on a
+machine with a TPU from claiming the chip.
 
 Usage:
     python -m repro.launch.dryrun --arch gemma2-9b --shape train_4k

@@ -8,19 +8,13 @@ any jax import; tests/benches see the real 1-CPU device).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types (Auto keeps GSPMD's behaviour)
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    # explicit Auto axes keep GSPMD's behaviour
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

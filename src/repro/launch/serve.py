@@ -3,8 +3,8 @@
 ``python -m repro.launch.serve --arch qwen2-7b --reduced --policy w4a8_abfp``
 drives synthetic requests through the ServeEngine and reports throughput +
 slot utilization.  The full-size serving graphs (decode_32k / long_500k)
-are exercised by the dry-run, not here — this launcher proves the engine
-logic end-to-end on real arrays.
+are exercised by the dry-run; ``chip_smoke.py`` builds its engines through
+``build_engine`` at published widths on the chip.
 """
 
 from __future__ import annotations
@@ -17,7 +17,7 @@ import jax
 import numpy as np
 
 
-def main() -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -93,23 +93,33 @@ def main() -> int:
                     "distribution)")
     ap.add_argument("--no-lint", action="store_true",
                     help="skip the qlint pre-flight gate")
-    args = ap.parse_args()
+    return ap
 
+
+def build_engine(args, cfg=None):
+    """Build the serving engine ``args`` (the parsed CLI options) describe.
+
+    ``cfg`` replaces the ``--arch``/``--reduced`` lookup when given (the
+    chip smoke serves a depth-cut config at published widths).  Returns
+    ``(engine, cfg, info)``; ``info`` carries the resolved policy name and
+    the recipe / expert-precision reports for the run summary.
+    """
     from repro.configs import get_config
     from repro.core.policy import preset
     from repro.models import build_model
     from repro.nn.module import unbox
-    from repro.serve.engine import PagedServeEngine, Request, ServeEngine
+    from repro.serve.engine import PagedServeEngine, ServeEngine
     from repro.serve.kv_pages import PageGeometry, pages_for
     from repro.serve.speculative import SpeculativeServeEngine
 
-    cfg = get_config(args.arch)
+    if cfg is None:
+        cfg = get_config(args.arch)
+        if args.reduced:
+            cfg = cfg.reduced()
     if cfg.family == "vit":
         raise SystemExit(
             f"{args.arch} is an encoder-only classifier: nothing to "
             "decode. Use `python -m benchmarks.run --only vit_table`.")
-    if args.reduced:
-        cfg = cfg.reduced()
 
     from repro.core.policy import has_layer_rules
 
@@ -268,6 +278,19 @@ def main() -> int:
             policy=policy, compress=args.compress,
             expert_cache=args.expert_cache,
         )
+    return engine, cfg, {"policy": policy_name, "recipe": recipe_info,
+                         "experts": expert_info}
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serve.engine import Request
+
+    enable_compile_cache()
+    engine, cfg, info = build_engine(args)
+    policy_name = info["policy"]
+    recipe_info, expert_info = info["recipe"], info["experts"]
     compress_info = {}
     if args.compress:
         from repro.models.serving_transforms import weight_bytes_summary

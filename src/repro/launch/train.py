@@ -145,6 +145,9 @@ def make_everything(args):
 
 def main() -> int:
     args = build_argparser().parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.checkpoint.manager import CheckpointConfig
     from repro.train.loop import LoopConfig, run
 

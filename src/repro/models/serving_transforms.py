@@ -65,7 +65,7 @@ from repro.core.policy import (
     has_site_rules,
     resolve_policy,
 )
-from repro.core.quantize import pack_int4_codes, quantize, unpack_int4_codes
+from repro.core.quantize import int4_nibbles, pack_int4_codes, quantize
 from repro.core.simulate import qdq_weight
 
 
@@ -73,21 +73,26 @@ from repro.core.simulate import qdq_weight
 class CompressedKernel:
     """int codes + per-group unit scales; metadata rides as pytree aux.
 
-    codes: ``(N, G, n)`` int8 — contraction grouped last — or, when
-    ``packed``, ``(N, G, n//2)`` uint8 nibble pairs (INT4 storage).
-    scale: ``(N, G)`` f32 unit scales (alpha / qmax).  ``fmt_name`` records
-    the stored integer format so reports/backends can reason about the bit
-    budget without the policy in hand.
+    codes: ``(Kp, N)`` int8 in the dense kernel's own orientation, the
+    contraction zero-padded to ``Kp = G * n`` — or, when ``packed``,
+    ``(Kp // 2, N)`` uint8 (INT4 storage): group g's ``n // 2`` byte rows
+    hold its first half of code rows in the low nibbles and its second
+    half in the high nibbles.  scale: ``(G, N)`` f32 unit scales
+    (alpha / qmax), row g covering code rows ``[g*n, (g+1)*n)``.  Stacked
+    kernels lead with their stack dims.  Both the jnp backend and the
+    Pallas kernel read this layout as stored (on the TPU neither
+    relayouts it per call).
+    ``fmt_name`` records the stored integer format so reports/backends can
+    reason about the bit budget without the policy in hand.
     """
 
-    __slots__ = ("codes", "scale", "axis", "pad", "k", "dtype", "fmt_name",
+    __slots__ = ("codes", "scale", "pad", "k", "dtype", "fmt_name",
                  "packed")
 
-    def __init__(self, codes, scale, axis: int, pad: int, k: int,
-                 dtype: str, fmt_name: str = "int8", packed: bool = False):
+    def __init__(self, codes, scale, pad: int, k: int, dtype: str,
+                 fmt_name: str = "int8", packed: bool = False):
         self.codes = codes
         self.scale = scale
-        self.axis = axis
         self.pad = pad
         self.k = k
         self.dtype = dtype
@@ -95,9 +100,8 @@ class CompressedKernel:
         self.packed = packed
 
     def tree_flatten(self):
-        return (self.codes, self.scale), (self.axis, self.pad, self.k,
-                                          self.dtype, self.fmt_name,
-                                          self.packed)
+        return (self.codes, self.scale), (self.pad, self.k, self.dtype,
+                                          self.fmt_name, self.packed)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -106,8 +110,28 @@ class CompressedKernel:
     @property
     def group(self) -> int:
         """Stored group length n (in codes, not bytes — packing-aware)."""
-        n = self.codes.shape[-1]
-        return n * 2 if self.packed else n
+        rows = self.codes.shape[-2] * (2 if self.packed else 1)
+        return rows // self.scale.shape[-2]
+
+    def nibbles(self):
+        """Packed codes -> ``(low, high)`` int8, each ``(..., G, n//2, N)``:
+        the first and second half of every group's code rows."""
+        *lead, rows, N = self.codes.shape
+        G = self.scale.shape[-2]
+        return int4_nibbles(self.codes.reshape(*lead, G, rows // G, N))
+
+    def grouped_codes(self):
+        """The codes as int8 ``(..., G, n, N)``, unpacked if stored packed."""
+        if self.packed:
+            return jnp.concatenate(self.nibbles(), axis=-2)
+        *lead, rows, N = self.codes.shape
+        G = self.scale.shape[-2]
+        return self.codes.reshape(*lead, G, rows // G, N)
+
+    def int8_codes(self):
+        """The ``(..., Kp, N)`` codes as int8, unpacked if stored packed."""
+        c = self.grouped_codes()
+        return c.reshape(*c.shape[:-3], -1, c.shape[-1])
 
     def __repr__(self):
         return (f"CompressedKernel(codes={getattr(self.codes, 'shape', None)},"
@@ -358,29 +382,42 @@ def compress_kernel(w, tq: TensorQuant) -> CompressedKernel:
             w, tq.fmt, axis=axis, n=tq.group, dtype=jnp.int8,
             scale_dtype=jnp.dtype(tq.scale_dtype),
         )
+        n = tq.group
+        # (..., N, G, n) / (..., N, G) -> (..., G*n, N) / (..., G, N)
+        codes = jnp.swapaxes(codes.reshape(*codes.shape[:-2], -1), -1, -2)
+        scales = jnp.swapaxes(scales, -1, -2)
     elif tq.scaler == "channel_max":
         # one group spanning K, alpha = per-output-channel max (matches
         # core.simulate.qdq_weight's channel_max path bit-for-bit)
-        wm = jnp.moveaxis(w, axis, -1)[..., None, :]  # (..., N, 1, K)
         alpha = jnp.maximum(
-            jnp.max(jnp.abs(wm), axis=-1, keepdims=True), 1e-8
+            jnp.max(jnp.abs(w), axis=axis, keepdims=True), 1e-8
         )
-        codes, scale = quantize(wm, alpha, tq.fmt, dtype=jnp.int8)
-        scales = scale[..., 0]
+        codes, scales = quantize(w, alpha, tq.fmt, dtype=jnp.int8)
         pad, k = 0, w.shape[axis]
+        n = k
     else:
         raise ValueError(
             f"compress_kernel supports 'abfp'/'channel_max' weight "
             f"scalers, got {tq.scaler!r}"
         )
-    packed = tq.fmt.bits <= 4 and codes.shape[-1] % 2 == 0
+    # each group packs its two halves into one byte plane, so n must be even
+    packed = tq.fmt.bits <= 4 and n % 2 == 0
     if packed:
-        codes = pack_int4_codes(codes)
+        *lead, kp, N = codes.shape
+        codes = pack_int4_codes(codes.reshape(*lead, kp // n, n, N), axis=-2)
+        codes = codes.reshape(*lead, kp // 2, N)
     # `scales` are already UNIT scales (alpha/qmax); keep f32 — they are
     # 1/group of the codes count, and f32 keeps serving numerics exact.
     return CompressedKernel(codes, scales.astype(jnp.float32),
-                            -2, pad, k, str(w.dtype),
+                            pad, k, str(w.dtype),
                             fmt_name=tq.fmt.name, packed=packed)
+
+
+# One fused program per kernel shape: run op by op, the quantize chain
+# materializes several f32 copies of the kernel (an embedding-sized
+# readout is 2 GiB each), which a chip holding the dense weights has no
+# room for.  The codes are the same either way.
+_compress_kernel_fused = jax.jit(compress_kernel, static_argnums=1)
 
 
 def compress_weights(params, policy: Policy):
@@ -402,7 +439,7 @@ def compress_weights(params, policy: Policy):
             return w
         if isinstance(tq.fmt, IntFormat) and tq.scaler in ("abfp",
                                                            "channel_max"):
-            return compress_kernel(w, tq)
+            return _compress_kernel_fused(w, tq)
         # float formats / exotic scalers: no integer codes to store —
         # prequantize offline so serving still matches the QDQ simulation
         return qdq_weight(w, tq, contract_axis=w.ndim - 2).astype(w.dtype)
@@ -431,8 +468,8 @@ def compress_axes(axes_tree, compressed_sds_tree):
     """Mirror ``compress_weights`` on the logical-axes tree.
 
     For a kernel with axes (a_contract, a_out) the codes are laid out
-    (a_out, G, n) and scales (a_out, G) — sharding follows the surviving
-    output axis; group dims replicate.  Pytree aux metadata is copied from
+    (Kp, a_out) and scales (G, a_out) — sharding follows the output axis;
+    the grouped contraction replicates.  Pytree aux metadata is copied from
     the compressed SDS tree so treedefs match exactly under jit.  Dense
     (uncompressed / fp32-rule) kernels keep their original axes.
     """
@@ -445,9 +482,9 @@ def compress_axes(axes_tree, compressed_sds_tree):
             lead = tuple(axes[:-2]) if len(axes) > 2 else ()
             a_out = axes[-1]
             return CompressedKernel(
-                codes=lead + (a_out, None, None),
-                scale=lead + (a_out, None),
-                axis=sds_node.axis, pad=sds_node.pad, k=sds_node.k,
+                codes=lead + (None, a_out),
+                scale=lead + (None, a_out),
+                pad=sds_node.pad, k=sds_node.k,
                 dtype=sds_node.dtype, fmt_name=sds_node.fmt_name,
                 packed=sds_node.packed,
             )
@@ -473,15 +510,12 @@ def compress_axes(axes_tree, compressed_sds_tree):
 def decompress_kernel(entry: CompressedKernel, dtype=None):
     """codes+scales -> dense kernel (fused by XLA into the consumer)."""
     dt = jnp.dtype(dtype or entry.dtype)
-    codes = entry.codes
-    if entry.packed:
-        codes = unpack_int4_codes(codes)
-    w = codes.astype(dt) * entry.scale.astype(dt)[..., None]
-    # (…, N, G, n) -> flatten -> unpad -> contraction back to rank-2
-    w = w.reshape(*w.shape[:-2], w.shape[-2] * w.shape[-1])
+    codes = entry.grouped_codes()
+    w = codes.astype(dt) * entry.scale.astype(dt)[..., None, :]
+    w = w.reshape(*codes.shape[:-3], -1, codes.shape[-1])
     if entry.pad:
-        w = w[..., :entry.k]
-    return jnp.moveaxis(w, -1, entry.axis)  # axis == -2 (end-relative)
+        w = w[..., :entry.k, :]
+    return w
 
 
 def is_compressed(kernel) -> bool:

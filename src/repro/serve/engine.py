@@ -568,6 +568,21 @@ class PagedServeEngine(_EngineBase):
         toks, new_keys = serve_steps.sample_step(logits, keys, temps, topk)
         return toks[:, 0], state, new_keys
 
+    def compile_steps(self) -> dict:
+        """Compile both step shapes ahead of the first tick — the prefill
+        chunk ``(n_slots, prefill_chunk)`` and decode ``(n_slots, 1)`` —
+        and return the compiled programs by name."""
+        n = self.n_slots
+        return {
+            name: self._step.lower(
+                self.params, jnp.zeros((n, width), jnp.int32), self.state,
+                jnp.zeros((n,), jnp.int32), self._keys,
+                jnp.asarray(self._temps), jnp.asarray(self._topk),
+            ).compile()
+            for name, width in (("prefill", self.geometry.prefill_chunk),
+                                ("decode", 1))
+        }
+
     def _masked_table(self, mask: np.ndarray) -> jnp.ndarray:
         """Device table with non-participating rows unmapped (-1): their
         writes route to the trash page inside the step."""

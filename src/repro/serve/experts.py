@@ -116,7 +116,7 @@ def _dense_entry_bytes(entry) -> int:
     """f32-equivalent dense bytes of one expert entry."""
     if isinstance(entry, st.CompressedKernel):
         lead_n = 1
-        for d in entry.codes.shape[:-2]:
+        for d in entry.codes.shape[:-2] + entry.codes.shape[-1:]:
             lead_n *= int(d)
         return lead_n * entry.k * jnp.dtype(entry.dtype).itemsize
     return st.entry_bytes(entry)

@@ -136,6 +136,15 @@ def test_int4_pack_roundtrip():
     rng = np.random.RandomState(8)
     c = jnp.asarray(rng.randint(-7, 8, (5, 3, 64)), jnp.int8)
     assert (unpack_int4_codes(pack_int4_codes(c)) == c).all()
+    # first half in the low nibbles, second half in the high ones
+    q = pack_int4_codes(jnp.asarray([1, -2, -8, 7], jnp.int8))
+    assert q.tolist() == [0x81, 0x7E]
+    assert unpack_int4_codes(q).tolist() == [1, -2, -8, 7]
+    # stored kernels pack the two halves of each group's contraction rows
+    rows = c[:, :2]
+    packed = pack_int4_codes(rows, axis=-2)
+    assert packed.shape == (5, 1, 64) and packed.dtype == jnp.uint8
+    assert (unpack_int4_codes(packed, axis=-2) == rows).all()
     with pytest.raises(ValueError, match="even last dim"):
         pack_int4_codes(jnp.zeros((2, 3), jnp.int8))
 
@@ -153,12 +162,9 @@ def test_quant_matmul_kernel_vs_qdq_reference(fmt, n, mkn):
     w = jnp.asarray(rng.randn(K, N), jnp.float32)
     tq = TensorQuant(fmt.name, scaler="abfp", group=n)
     pol = QuantPolicy(name="t", input=tq, weight=tq)
-    # store codes UNPACKED (the Pallas kernel's representation)
-    from repro.core.abfp import abfp_quantize
-
-    codes, scales, (pad, k) = abfp_quantize(w, fmt, axis=0, n=n,
-                                            dtype=jnp.int8)
-    got = quant_matmul(x, codes, scales.astype(jnp.float32), fmt, n=n,
+    # the stored layout, codes UNPACKED (the Pallas kernel's representation)
+    ck = st.compress_kernel(w, tq)
+    got = quant_matmul(x, ck.int8_codes(), ck.scale, fmt, n=n,
                        block_m=kops.fit_block(M),
                        block_n=kops.fit_block(N), interpret=True)
     want = sim.qmatmul(x, w, pol)
@@ -222,13 +228,18 @@ def test_kernel_shape_errors_name_offenders():
                    interpret=True)
     with pytest.raises(ValueError, match="w_codes"):
         quant_matmul(jnp.zeros((8, 64), jnp.float32),
-                     jnp.zeros((16, 64), jnp.int8),
-                     jnp.zeros((16, 1), jnp.float32), INT8, n=64,
+                     jnp.zeros((16, 1, 64), jnp.int8),
+                     jnp.zeros((1, 16), jnp.float32), INT8, n=64,
                      interpret=True)
     with pytest.raises(ValueError, match="cover K=128"):
         quant_matmul(jnp.zeros((8, 64), jnp.float32),
-                     jnp.zeros((16, 2, 64), jnp.int8),
-                     jnp.zeros((16, 2), jnp.float32), INT8, n=64,
+                     jnp.zeros((128, 16), jnp.int8),
+                     jnp.zeros((2, 16), jnp.float32), INT8, n=64,
+                     interpret=True)
+    with pytest.raises(ValueError, match=r"w_scales shape \(16, 1\)"):
+        quant_matmul(jnp.zeros((8, 64), jnp.float32),
+                     jnp.zeros((64, 16), jnp.int8),
+                     jnp.zeros((16, 1), jnp.float32), INT8, n=64,
                      interpret=True)
 
 
@@ -301,7 +312,7 @@ def test_per_site_compression_w4ffn_fp8attn_mse(opt_setup):
     assert not st.is_compressed(aq)  # FP8 rule: dense (prequantized)
     k = comp["blocks"][0]["ffn"]["wi"]["kernel"]
     assert st.is_compressed(k) and k.fmt_name == "int4"
-    assert k.codes.shape[-3:-1] == (cfg.d_ff, 1)  # channel_max: one group
+    assert k.scale.shape[-2:] == (1, cfg.d_ff)  # channel_max: one group
     batch = {"tokens": np.random.RandomState(4).randint(
         0, 131, (2, 16)).astype(np.int32)}
     # no q tree: both sides fall back to dynamic-max inputs identically
@@ -390,8 +401,8 @@ def test_compress_axes_mixed_tree(opt_setup):
     caxes = st.compress_axes(axes, csds)
     ffn_ax = caxes["blocks"][0]["ffn"]["wi"]["kernel"]
     assert st.is_compressed(ffn_ax)
-    assert ffn_ax.codes == ("mlp", None, None)
-    assert ffn_ax.scale == ("mlp", None)
+    assert ffn_ax.codes == (None, "mlp")
+    assert ffn_ax.scale == (None, "mlp")
     attn_ax = caxes["blocks"][0]["attn"]["q"]["kernel"]
     assert not st.is_compressed(attn_ax)
     assert attn_ax == ("embed", "qkv")

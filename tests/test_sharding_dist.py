@@ -256,23 +256,16 @@ def test_gradient_compression_pod_allreduce():
     result = run_in_devices("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        try:  # jax >= 0.6
-            from jax import shard_map
-            _sm_kw = {"check_vma": False}
-        except ImportError:  # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
-            _sm_kw = {"check_rep": False}
+        from jax import shard_map
         from repro.optim.compression import compressed_psum_pod
 
-        # plain make_mesh: axis_types defaults to Auto on jax >= 0.5 and
-        # doesn't exist on 0.4.x
         mesh = jax.make_mesh((2, 4), ("pod", "data"))
         g = jax.random.normal(jax.random.PRNGKey(0), (2, 256))
         e0 = jnp.zeros((1, 256))
 
         @partial(shard_map, mesh=mesh,
                  in_specs=(P("pod"), P()), out_specs=(P(), P("pod")),
-                 **_sm_kw)
+                 check_vma=False)
         def run(gl, el):
             red, enew = compressed_psum_pod(gl[0], el[0], mesh)
             return red[None] / 1.0, enew[None]
