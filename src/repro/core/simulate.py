@@ -34,6 +34,7 @@ which prefer the compressed backend for compressed storage).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, NamedTuple
 
 import jax
@@ -356,12 +357,14 @@ def qmatmul(
             "route CompressedKernel weights to a compressed-consuming "
             "backend (decompress explicitly if densification is intended)"
         )
-    y = backend.fn(x, w, policy, site=site, in_alpha=in_alpha,
-                   compute_dtype=compute_dtype)
-    if policy.output is not None:
-        y = qdq_activation(
-            y, policy.output, axis=-1, site=site + "/out", alpha=out_alpha
-        )
+    # the site names the matmul's ops in a profiler trace
+    with jax.named_scope(site) if site else contextlib.nullcontext():
+        y = backend.fn(x, w, policy, site=site, in_alpha=in_alpha,
+                       compute_dtype=compute_dtype)
+        if policy.output is not None:
+            y = qdq_activation(
+                y, policy.output, axis=-1, site=site + "/out",
+                alpha=out_alpha)
     return y
 
 

@@ -1,17 +1,18 @@
 """Serving launcher: continuous-batching engine over a reduced or full arch.
 
 ``python -m repro.launch.serve --arch qwen2-7b --reduced --policy w4a8_abfp``
-drives synthetic requests through the ServeEngine and reports throughput +
-slot utilization.  The full-size serving graphs (decode_32k / long_500k)
-are exercised by the dry-run; ``chip_smoke.py`` builds its engines through
-``build_engine`` at published widths on the chip.
+drives synthetic requests through the ServeEngine and reports what it
+served, with the spans and waste counts the paged engine records
+(``serve.tracing``), summed over the run.  The full-size serving graphs
+(decode_32k / long_500k) are exercised by the dry-run; ``chip_smoke.py``
+builds its engines through ``build_engine`` at published widths on the
+chip.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import time
 
 import jax
 import numpy as np
@@ -282,9 +283,21 @@ def build_engine(args, cfg=None):
                          "experts": expert_info}
 
 
+def host_seconds(spans: dict):
+    """The host's own seconds in the engine's ticks, from the recorder's
+    totals: ticks less the step calls and read-backs inside them; None
+    for an engine that records no ticks."""
+    if "serve.tick" not in spans:
+        return None
+    return spans["serve.tick"]["s"] - sum(
+        spans[k]["s"] for k in ("serve.step", "serve.readback")
+        if k in spans)
+
+
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     from repro.launch.compile_cache import enable_compile_cache
+    from repro.serve import tracing
     from repro.serve.engine import Request
 
     enable_compile_cache()
@@ -315,9 +328,8 @@ def main(argv=None) -> int:
                 top_k=args.top_k,
             )
         )
-    t0 = time.perf_counter()
     done = engine.run_until_done()
-    dt = time.perf_counter() - t0
+    spans = tracing.totals()
     total_tokens = sum(len(c.tokens) for c in done)
     # per-request completion metadata (not just aggregate tok/s): accept
     # counts and target steps are per-request facts, so report them there
@@ -410,8 +422,8 @@ def main(argv=None) -> int:
                 "requests": len(done),
                 "generated_tokens": total_tokens,
                 "ticks": engine.ticks,
-                "wall_s": round(dt, 3),
-                "tokens_per_s": round(total_tokens / dt, 1),
+                "host_s": host_seconds(spans),
+                "spans": spans,
                 "completions": completions,
                 **recipe_info,
                 **compress_info,

@@ -743,12 +743,15 @@ class TransformerLM:
         )
         if all_logits:
             x = _norm(c).apply(params["final_norm"], x)
-            return self.head_logits(params, x, policy), new_state
+            with jax.named_scope("readout"):
+                logits = self.head_logits(params, x, policy)
+            return logits, new_state
         sel = jnp.maximum(n_valid - 1, 0)[:, None, None]
         x = jnp.take_along_axis(
             x, jnp.broadcast_to(sel, (B, 1, x.shape[-1])), axis=1)
         x = _norm(c).apply(params["final_norm"], x)
-        logits = self.head_logits(params, x, policy)
+        with jax.named_scope("readout"):
+            logits = self.head_logits(params, x, policy)
         return logits[:, 0], new_state
 
 

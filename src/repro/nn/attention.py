@@ -965,14 +965,17 @@ class Attention:
             # invalid/trash positions carry kv_pos = -1, which the kernel
             # turns into probability-exactly-0 (trash never reaches the
             # output), and the page scales broadcast over their tokens.
-            gk = cache.k[phys_tab].reshape(B, T, self.n_kv, self.head_dim)
-            gv = cache.v[phys_tab].reshape(B, T, self.n_kv, self.head_dim)
-            sk = jnp.broadcast_to(
-                cache.k_scale[phys_tab][:, :, None, :],
-                (B, NL, ps, self.n_kv)).reshape(B, T, self.n_kv)
-            sv = jnp.broadcast_to(
-                cache.v_scale[phys_tab][:, :, None, :],
-                (B, NL, ps, self.n_kv)).reshape(B, T, self.n_kv)
+            with jax.named_scope("gather"):
+                gk = cache.k[phys_tab].reshape(B, T, self.n_kv,
+                                               self.head_dim)
+                gv = cache.v[phys_tab].reshape(B, T, self.n_kv,
+                                               self.head_dim)
+                sk = jnp.broadcast_to(
+                    cache.k_scale[phys_tab][:, :, None, :],
+                    (B, NL, ps, self.n_kv)).reshape(B, T, self.n_kv)
+                sv = jnp.broadcast_to(
+                    cache.v_scale[phys_tab][:, :, None, :],
+                    (B, NL, ps, self.n_kv)).reshape(B, T, self.n_kv)
             out = attn_backends()["compressed"].fn(
                 self._quant_q(pol, qh, q), gk, gv, sk, sv,
                 positions, kv_pos, window,
@@ -980,22 +983,24 @@ class Attention:
                 probs_tq=self._attn_probs_tq(pol),
             ).astype(jnp.dtype(self.dtype))
         else:
-            gk = cache.k[phys_tab]  # (B, NL, ps, F)
-            gv = cache.v[phys_tab]
-            if mode != "fp":
-                sk = cache.k_scale[phys_tab][:, :, None, :, None]
-                sv = cache.v_scale[phys_tab][:, :, None, :, None]
-                gk = gk.reshape(B, NL, ps, self.n_kv, self.head_dim)
-                gv = gv.reshape(B, NL, ps, self.n_kv, self.head_dim)
-                gk = (gk.astype(jnp.float32) * sk).astype(
-                    jnp.dtype(self.dtype))
-                gv = (gv.astype(jnp.float32) * sv).astype(
-                    jnp.dtype(self.dtype))
-            gk = gk.reshape(B, T, self.n_kv, self.head_dim)
-            gv = gv.reshape(B, T, self.n_kv, self.head_dim)
-            # zero-mask: requant group maxima must see zeros, never trash
-            gk = gk * valid[..., None, None].astype(gk.dtype)
-            gv = gv * valid[..., None, None].astype(gv.dtype)
+            with jax.named_scope("gather"):
+                gk = cache.k[phys_tab]  # (B, NL, ps, F)
+                gv = cache.v[phys_tab]
+                if mode != "fp":
+                    sk = cache.k_scale[phys_tab][:, :, None, :, None]
+                    sv = cache.v_scale[phys_tab][:, :, None, :, None]
+                    gk = gk.reshape(B, NL, ps, self.n_kv, self.head_dim)
+                    gv = gv.reshape(B, NL, ps, self.n_kv, self.head_dim)
+                    gk = (gk.astype(jnp.float32) * sk).astype(
+                        jnp.dtype(self.dtype))
+                    gv = (gv.astype(jnp.float32) * sv).astype(
+                        jnp.dtype(self.dtype))
+                gk = gk.reshape(B, T, self.n_kv, self.head_dim)
+                gv = gv.reshape(B, T, self.n_kv, self.head_dim)
+                # zero-mask: requant group maxima must see zeros, never
+                # trash
+                gk = gk * valid[..., None, None].astype(gk.dtype)
+                gv = gv * valid[..., None, None].astype(gv.dtype)
             out = self._reference(qh, gk, gv, positions, kv_pos, window,
                                   policy, q=q,
                                   kv_prequant=kv_on_write or mode != "fp")

@@ -61,6 +61,7 @@ from repro.core.policy import (Policy, QuantPolicy, attn_backend_mode,
                                kv_cache_mode)
 from repro.models.lm import DecodeState
 from repro.serve import steps as serve_steps
+from repro.serve import tracing
 from repro.serve.kv_pages import (PageGeometry, PagePool,
                                   attention_read_bytes, check_geometry,
                                   pages_for, resident_kv_bytes)
@@ -565,7 +566,9 @@ class PagedServeEngine(_EngineBase):
     def _step_fn(self, params, tokens, state, n_valid, keys, temps, topk):
         logits, state = self.model.paged_step(
             params, tokens, state, n_valid=n_valid, policy=self.policy)
-        toks, new_keys = serve_steps.sample_step(logits, keys, temps, topk)
+        with jax.named_scope("sample"):
+            toks, new_keys = serve_steps.sample_step(logits, keys, temps,
+                                                     topk)
         return toks[:, 0], state, new_keys
 
     def compile_steps(self) -> dict:
@@ -623,60 +626,94 @@ class PagedServeEngine(_EngineBase):
         rows = [s for s in range(self.n_slots) if self.prefilling[s]]
         if not rows:
             return
-        C = self.geometry.prefill_chunk
-        tokens = np.zeros((self.n_slots, C), np.int32)
-        n_valid = np.zeros((self.n_slots,), np.int32)
-        for s in rows:
-            p = self.req[s].prompt
-            off = self._pf_pos[s]
-            m = min(C, len(p) - off)
-            tokens[s, :m] = p[off:off + m]
-            n_valid[s] = m
-        state = self.state._replace(pages=self.state.pages._replace(
-            table=self._masked_table(self.prefilling)))
-        tok, state, self._keys = self._step(
-            self.params, jnp.asarray(tokens), state, jnp.asarray(n_valid),
-            self._keys, jnp.asarray(self._temps), jnp.asarray(self._topk))
-        self.state = state
-        toks = np.asarray(jax.device_get(tok)).reshape(-1)
-        for s in rows:
-            self._pf_pos[s] += int(n_valid[s])
-            if self._pf_pos[s] < len(self.req[s].prompt):
-                continue
-            first = int(toks[s])
-            self.prefilling[s] = False
-            self.active[s] = True
-            self.generated[s] = [first]
-            self._cur[s, 0] = first
-            req = self.req[s]
-            if req.eos_id is not None and first == req.eos_id:
-                self._evict(s, "eos")
-            elif req.max_new_tokens <= 1:
-                self._evict(s, "length")
+        with tracing.span("serve.prefill") as counts:
+            with tracing.span("serve.prepare"):
+                C = self.geometry.prefill_chunk
+                tokens = np.zeros((self.n_slots, C), np.int32)
+                n_valid = np.zeros((self.n_slots,), np.int32)
+                for s in rows:
+                    p = self.req[s].prompt
+                    off = self._pf_pos[s]
+                    m = min(C, len(p) - off)
+                    tokens[s, :m] = p[off:off + m]
+                    n_valid[s] = m
+                state = self.state._replace(pages=self.state.pages._replace(
+                    table=self._masked_table(self.prefilling)))
+                args = (self.params, jnp.asarray(tokens), state,
+                        jnp.asarray(n_valid), self._keys,
+                        jnp.asarray(self._temps), jnp.asarray(self._topk))
+            counts.update(self._step_counts(
+                n_valid, tokens.size,
+                [self._pf_pos[s] + int(n_valid[s]) for s in rows]))
+            with tracing.span("serve.step"):
+                tok, state, self._keys = self._step(*args)
+            with tracing.span("serve.readback"):
+                toks = np.asarray(jax.device_get(tok)).reshape(-1)
+            with tracing.span("serve.update"):
+                self.state = state
+                for s in rows:
+                    self._pf_pos[s] += int(n_valid[s])
+                    if self._pf_pos[s] < len(self.req[s].prompt):
+                        continue
+                    first = int(toks[s])
+                    self.prefilling[s] = False
+                    self.active[s] = True
+                    self.generated[s] = [first]
+                    self._cur[s, 0] = first
+                    req = self.req[s]
+                    if req.eos_id is not None and first == req.eos_id:
+                        self._evict(s, "eos")
+                    elif req.max_new_tokens <= 1:
+                        self._evict(s, "length")
 
     # --------------------------------------------------------------- decode
     def _decode_tick(self):
-        if not self.active.any():
+        rows = [s for s in range(self.n_slots) if self.active[s]]
+        if not rows:
             return
-        state = self.state._replace(pages=self.state.pages._replace(
-            table=self._masked_table(self.active)))
-        tok, state, self._keys = self._step(
-            self.params, jnp.asarray(self._cur), state,
-            jnp.asarray(self.active.astype(np.int32)),
-            self._keys, jnp.asarray(self._temps), jnp.asarray(self._topk))
-        self.state = state
-        toks = np.asarray(jax.device_get(tok)).reshape(-1)
-        for slot in range(self.n_slots):
-            if not self.active[slot]:
-                continue
-            req = self.req[slot]
-            t = int(toks[slot])
-            self.generated[slot].append(t)
-            self._cur[slot, 0] = t
-            if req.eos_id is not None and t == req.eos_id:
-                self._evict(slot, "eos")
-            elif len(self.generated[slot]) >= req.max_new_tokens:
-                self._evict(slot, "length")
+        with tracing.span("serve.decode") as counts:
+            with tracing.span("serve.prepare"):
+                n_valid = self.active.astype(np.int32)
+                state = self.state._replace(pages=self.state.pages._replace(
+                    table=self._masked_table(self.active)))
+                args = (self.params, jnp.asarray(self._cur), state,
+                        jnp.asarray(n_valid), self._keys,
+                        jnp.asarray(self._temps), jnp.asarray(self._topk))
+            # each row writes its last token after the context it holds
+            counts.update(self._step_counts(
+                n_valid, self._cur.size,
+                [len(self.req[s].prompt) + len(self.generated[s])
+                 for s in rows]))
+            with tracing.span("serve.step"):
+                tok, state, self._keys = self._step(*args)
+            with tracing.span("serve.readback"):
+                toks = np.asarray(jax.device_get(tok)).reshape(-1)
+            with tracing.span("serve.update"):
+                self.state = state
+                for slot in rows:
+                    req = self.req[slot]
+                    t = int(toks[slot])
+                    self.generated[slot].append(t)
+                    self._cur[slot, 0] = t
+                    if req.eos_id is not None and t == req.eos_id:
+                        self._evict(slot, "eos")
+                    elif len(self.generated[slot]) >= req.max_new_tokens:
+                        self._evict(slot, "length")
+
+    def _step_counts(self, n_valid: np.ndarray, computed: int,
+                     context: list[int]) -> dict:
+        """Waste counts of one step call, from host state alone.
+
+        ``rows_valid`` / ``rows_computed``: token rows that carry a prompt
+        or decode token, against the rows the step computes;
+        ``pages_live`` / ``pages_read``: pages that hold the participating
+        rows' ``context`` after the call, against the page-table entries
+        the step gathers (``paged_step`` reads every one).
+        """
+        ps = self.geometry.page_size
+        return {"rows_valid": int(n_valid.sum()), "rows_computed": computed,
+                "pages_live": sum(pages_for(n, ps) for n in context),
+                "pages_read": self.table.size}
 
     def _evict(self, slot: int, reason: str):
         self._complete(slot, reason)
@@ -694,9 +731,16 @@ class PagedServeEngine(_EngineBase):
     def tick(self):
         """Admit -> one prefill chunk per prefilling slot -> one decode
         step over the active slots."""
-        self._admit()
-        self._prefill_tick()
-        self._decode_tick()
+        with tracing.span("serve.tick"):
+            with tracing.span("serve.admit") as counts:
+                queued = len(self.queue)
+                self._admit()
+                counts["admitted"] = queued - len(self.queue)
+                # a free slot left over means the head waits for pages
+                counts["blocked"] = int(bool(self.queue) and not (
+                    self.active | self.prefilling).all())
+            self._prefill_tick()
+            self._decode_tick()
         self.ticks += 1
 
     # ----------------------------------------------------------- reporting
