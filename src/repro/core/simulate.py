@@ -19,7 +19,9 @@ declaring the weight representation it consumes:
   fused      dense       Pallas fused QDQ+matmul kernel (repro.kernels)
   compressed codes       contract PRE-QUANTIZED weight codes + per-group unit
                          scales directly (int32 accumulate, per-group
-                         rescale) — HBM never sees a dequantized kernel
+                         rescale) — HBM never sees a dequantized kernel;
+                         on the TPU the aligned int-ABFP case runs the
+                         stored-codes Pallas kernel (``codes_kernel_takes``)
   ========== =========== =====================================================
 
 Selection (``execution_backend``): a ``CompressedKernel`` weight always
@@ -275,17 +277,61 @@ def _fused_backend(x, w, policy, *, site, in_alpha, compute_dtype):
     )
 
 
+_SITE_TALLIES: list[dict] = []
+
+
+@contextlib.contextmanager
+def compressed_site_tally():
+    """Count the compressed sites traced inside the block, by contraction.
+
+    Yields ``{"qmm_kernel_sites": k, "qmm_fallback_sites": f}``: sites
+    that lowered to the stored-codes Pallas kernel, and sites that took
+    the jnp einsum path.  The counts are of traced sites, so a site inside
+    a scanned layer stack counts once.
+    """
+    tally = {"qmm_kernel_sites": 0, "qmm_fallback_sites": 0}
+    _SITE_TALLIES.append(tally)
+    try:
+        yield tally
+    finally:
+        _SITE_TALLIES.pop()  # blocks nest: the last one opened closes
+
+
+def codes_kernel_takes(w, policy: QuantPolicy) -> bool:
+    """Whether a compressed site contracts in the stored-codes kernel.
+
+    It does where the input is int ABFP at the stored group (the aligned
+    case) and either the backend is a TPU and the shapes tile — N a
+    multiple of 128, the stored codes whole groups, a group's stored rows
+    whole (32, 128) int8 tiles — or ``policy.fused`` forces the kernel (in
+    interpret mode off the TPU).  Everything else keeps
+    ``_compressed_group_matmul``.
+    """
+    from repro.kernels import ops as kops  # lazy: pallas import
+
+    tq = policy.input
+    if not (tq is not None and isinstance(tq.fmt, IntFormat)
+            and tq.scaler == "abfp" and tq.group == w.group
+            and w.codes.ndim == 2):
+        return False
+    G, N = w.scale.shape
+    per_code = 2 if w.packed else 1
+    tiles = (N % 128 == 0 and w.codes.shape[0] * per_code == G * w.group
+             and w.group // per_code % 32 == 0)
+    return policy.fused or (not kops.should_interpret() and tiles)
+
+
 @register_backend("compressed", weight_repr="compressed")
 def _compressed_backend(x, w, policy, *, site, in_alpha, compute_dtype):
     """Serve pre-quantized weight codes straight into the contraction."""
-    tq = policy.input
-    if (policy.fused
-            and tq is not None and isinstance(tq.fmt, IntFormat)
-            and tq.scaler == "abfp" and tq.group == w.group):
+    kernel = codes_kernel_takes(w, policy)
+    for tally in _SITE_TALLIES:
+        tally["qmm_kernel_sites" if kernel else "qmm_fallback_sites"] += 1
+    if kernel:
         from repro.kernels import ops as kops  # lazy: pallas import
 
         return kops.quant_matmul_fused(
-            x, w, tq, interpret=kops.should_interpret()
+            x, w, policy.input, interpret=kops.should_interpret()
         )
     return _compressed_group_matmul(x, w, policy, site=site,
                                     in_alpha=in_alpha,

@@ -185,24 +185,19 @@ def abfp_matmul_fused(x, w, policy: QuantPolicy,
 def quant_matmul_fused(x, wk, tq_x, interpret: bool | None = None):
     """Compressed-domain Pallas dispatch: (…, K) x stored codes + scales.
 
-    ``wk`` is a ``CompressedKernel``, whose ``(Kp, N)`` codes and ``(G, N)``
-    scales the kernel reads as stored; packed INT4 codes are unpacked here
-    (the Pallas kernel consumes plain int8 codes).  x is zero-padded to
-    the stored (padded) contraction length so codes and activations tile
-    identically.
+    ``wk`` is a ``CompressedKernel``, whose ``(Kp, N)`` int8 or packed
+    ``(Kp/2, N)`` INT4 codes and ``(G, N)`` scales the kernel reads as
+    stored.  x is zero-padded to the stored (padded) contraction length
+    and quantized against ``tq_x`` as the jnp compressed path does, so
+    both contract the same codes.
     """
     interpret = should_interpret() if interpret is None else interpret
-    codes, scales, n = wk.int8_codes(), wk.scale, wk.group
-    N = codes.shape[-1]
     shape = x.shape
-    x2 = x.reshape(-1, shape[-1]).astype(jnp.float32)
+    x2 = x.reshape(-1, shape[-1])
     if wk.pad:
         x2 = jnp.pad(x2, ((0, 0), (0, wk.pad)))
-    bm = fit_block(x2.shape[0])
-    bn = fit_block(N)
-    bk = fit_block(x2.shape[1], start=512, multiple=n)
     y = _mm_mod.quant_matmul(
-        x2, codes, scales.astype(jnp.float32), tq_x.fmt, n=n,
-        block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
+        x2, wk.codes, wk.scale, tq_x.fmt, n=wk.group,
+        scale_dtype=jnp.dtype(tq_x.scale_dtype), interpret=interpret,
     )
-    return y.reshape(*shape[:-1], N)
+    return y.reshape(*shape[:-1], y.shape[-1])

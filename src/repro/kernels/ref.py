@@ -108,24 +108,39 @@ def quant_matmul_ref(x: jnp.ndarray, w_codes: jnp.ndarray,
                      w_scales: jnp.ndarray, fmt_x: IntFormat,
                      n: int = 64) -> jnp.ndarray:
     """Reference stored-codes matmul: x quantized to per-group int codes,
-    contracted against ``w_codes`` (K, N) group by group (rows
+    contracted against ``w_codes`` group by group (code rows
     ``[g*n, (g+1)*n)``), each group's integer sum rescaled by ``x``'s step
-    and ``w_scales`` (G, N)."""
+    and ``w_scales`` (G, N).  ``w_codes`` is (K, N) int8, or (K/2, N)
+    uint8 packed INT4: group g's n/2 byte rows hold its first n/2 code
+    rows in the low nibbles and the rest in the high ones.  Groups are
+    summed one at a time, so no (M, G, N) partial is held."""
     M, K = x.shape
-    K2, N = w_codes.shape
+    rows, N = w_codes.shape
     G = K // n
-    if K2 != K or G * n != K:
+    packed = w_codes.dtype == jnp.uint8
+    if rows * (2 if packed else 1) != K or G * n != K:
         raise ValueError(
             f"codes {w_codes.shape} do not cover x's K={K} in groups of "
             f"n={n}")
+    wc = w_codes.astype(jnp.int32).reshape(G, rows // G, N)
+    if packed:  # two's complement of each 4-bit field
+        wc = jnp.concatenate([((wc & 15) ^ 8) - 8, ((wc >> 4) ^ 8) - 8],
+                             axis=1)
     sx = _group_scales(x, -1, n) / fmt_x.qmax_pos  # (M, G)
     xg = x.astype(jnp.float32).reshape(M, G, n)
     xc = jnp.clip(jnp.round(xg / sx[..., None]), fmt_x.qmin, fmt_x.qmax_pos)
-    partial = jnp.einsum("mgk,gkn->mgn", xc,
-                         w_codes.astype(jnp.float32).reshape(G, n, N))
-    return jnp.einsum("mgn,mg,gn->mn", partial, sx,
-                      w_scales.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
+
+    def group(acc, g):
+        xc_g, wc_g, sx_g, sw_g = g
+        # integer-valued operands under 2^8: exact at any MXU precision
+        part = jnp.dot(xc_g, wc_g.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        return acc + part * sx_g[:, None] * sw_g[None, :], None
+
+    y, _ = jax.lax.scan(group, jnp.zeros((M, N), jnp.float32),
+                        (jnp.moveaxis(xc, 1, 0), wc, sx.T,
+                         w_scales.astype(jnp.float32)))
+    return y
 
 
 def flash_attention_quant_ref(qh, k_codes, v_codes, k_scale, v_scale,

@@ -6,9 +6,11 @@ for a described v5e (shapes only, via ``jax.eval_shape`` of ``make``), and
 oracle.  Every kernel is called with ``interpret=False``.
 
 Widths (arXiv:2407.10671): d_model 3584, d_ff 18944, 28 query / 4 KV
-heads of 128.  Serving shapes follow the chip smoke's engine: 4 slots,
-pages gathered to T = max_len = 512, prefill chunks of 64; the multi-block
-attention bodies run at T = 4096, past the single-block cut-off (2048).
+heads of 128, vocabulary 152064.  Serving shapes follow the chip smoke's
+engine: 4 slots, pages gathered to T = max_len = 512, prefill chunks of
+64; the multi-block attention bodies run at T = 4096, past the
+single-block cut-off (2048).  The stored-codes matmul also runs at the
+benchmark engine's 16 decode rows and 16 x 64 prefill rows.
 """
 
 from __future__ import annotations
@@ -30,6 +32,7 @@ from repro.kernels.quant_matmul import (abfp_matmul, abfp_matmul_int8,
 from repro.models.serving_transforms import compress_kernel
 
 D_MODEL, D_FF, HEADS, KV_HEADS, HEAD_DIM = 3584, 18944, 28, 4, 128
+VOCAB = 152064
 N_GROUP = 64  # w4a8_abfp's ABFP group
 SLOTS, MAX_LEN, CHUNK = 4, 512, 64
 
@@ -77,21 +80,23 @@ def _dense_w(key, m):
             _normal(kw, (D_MODEL, D_FF), D_MODEL ** -0.5))
 
 
-def _codes_w(key, m):
-    """x and w4a8_abfp's stored INT4 weight, unpacked to int8 codes."""
-    x, w = _dense_w(key, m)
-    ck = compress_kernel(w, TensorQuant("int4", scaler="abfp",
-                                        group=N_GROUP))
-    return x, ck.int8_codes(), ck.scale
+def _codes_w(key, m, k, n):
+    """x and w4a8_abfp's stored INT4 weight, packed as served."""
+    kx, kw = jax.random.split(key)
+    ck = compress_kernel(_normal(kw, (k, n), k ** -0.5),
+                         TensorQuant("int4", scaler="abfp", group=N_GROUP))
+    return _normal(kx, (m, k)), ck.codes, ck.scale
 
 
-def _quant_matmul_case(m, label):
+def _quant_matmul_case(m, label, k=D_MODEL, n=D_FF):
+    """The stored-codes kernel as the compressed backend calls it: packed
+    codes, the blocks ``codes_blocks`` picks for the shape."""
     return KernelCase(
         f"quant_matmul[{label} M={m}]",
         lambda x, c, s: quant_matmul(x, c, s, INT8, n=N_GROUP,
-                                     block_m=min(256, m), interpret=False),
+                                     interpret=False),
         lambda x, c, s: ref.quant_matmul_ref(x, c, s, INT8, N_GROUP),
-        lambda key: _codes_w(key, m), 1e-3, _MATMUL_WHY)
+        lambda key: _codes_w(key, m, k, n), 1e-3, _MATMUL_WHY)
 
 
 def _attn_inputs(key, s, t, code_dtype):
@@ -156,6 +161,15 @@ def kernel_cases() -> list[KernelCase]:
             lambda key: _dense_w(key, 256), 1e-3, _MATMUL_WHY),
         _quant_matmul_case(SLOTS, "decode"),
         _quant_matmul_case(SLOTS * CHUNK, "prefill"),
+    ]
+    # the benchmark's qwen2-7b engine: 16 decode rows, 16 x 64 prefill rows
+    for m in (16, 16 * CHUNK):
+        cases += [
+            _quant_matmul_case(m, "wi", D_MODEL, D_FF),
+            _quant_matmul_case(m, "wo", D_FF, D_MODEL),
+            _quant_matmul_case(m, "lm_head", D_MODEL, VOCAB),
+        ]
+    cases += [
         KernelCase(
             "flash_attention[S=T=512]",
             lambda q, k, v: flash_attention(q, k, v, causal=True,

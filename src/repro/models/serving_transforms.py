@@ -128,11 +128,6 @@ class CompressedKernel:
         G = self.scale.shape[-2]
         return self.codes.reshape(*lead, G, rows // G, N)
 
-    def int8_codes(self):
-        """The ``(..., Kp, N)`` codes as int8, unpacked if stored packed."""
-        c = self.grouped_codes()
-        return c.reshape(*c.shape[:-3], -1, c.shape[-1])
-
     def __repr__(self):
         return (f"CompressedKernel(codes={getattr(self.codes, 'shape', None)},"
                 f" scale={getattr(self.scale, 'shape', None)},"

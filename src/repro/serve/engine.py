@@ -59,6 +59,7 @@ import numpy as np
 from repro.analysis import messages as msg
 from repro.core.policy import (Policy, QuantPolicy, attn_backend_mode,
                                kv_cache_mode)
+from repro.core.simulate import compressed_site_tally
 from repro.models.lm import DecodeState
 from repro.serve import steps as serve_steps
 from repro.serve import tracing
@@ -561,11 +562,16 @@ class PagedServeEngine(_EngineBase):
         self._init_common(n_slots)
 
         self._step = jax.jit(self._step_fn)
+        # compressed matmul sites of each step program, by contraction
+        self._sites = {"prefill": {}, "decode": {}}
 
     # ---------------------------------------------------------- jitted fns
     def _step_fn(self, params, tokens, state, n_valid, keys, temps, topk):
-        logits, state = self.model.paged_step(
-            params, tokens, state, n_valid=n_valid, policy=self.policy)
+        with compressed_site_tally() as sites:
+            logits, state = self.model.paged_step(
+                params, tokens, state, n_valid=n_valid, policy=self.policy)
+        # runs while the step traces: one tally per step program
+        self._sites["decode" if tokens.shape[1] == 1 else "prefill"] = sites
         with jax.named_scope("sample"):
             toks, new_keys = serve_steps.sample_step(logits, keys, temps,
                                                      topk)
@@ -647,6 +653,7 @@ class PagedServeEngine(_EngineBase):
                 [self._pf_pos[s] + int(n_valid[s]) for s in rows]))
             with tracing.span("serve.step"):
                 tok, state, self._keys = self._step(*args)
+            counts.update(self._sites["prefill"])
             with tracing.span("serve.readback"):
                 toks = np.asarray(jax.device_get(tok)).reshape(-1)
             with tracing.span("serve.update"):
@@ -686,6 +693,7 @@ class PagedServeEngine(_EngineBase):
                  for s in rows]))
             with tracing.span("serve.step"):
                 tok, state, self._keys = self._step(*args)
+            counts.update(self._sites["decode"])
             with tracing.span("serve.readback"):
                 toks = np.asarray(jax.device_get(tok)).reshape(-1)
             with tracing.span("serve.update"):

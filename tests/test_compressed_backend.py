@@ -162,11 +162,9 @@ def test_quant_matmul_kernel_vs_qdq_reference(fmt, n, mkn):
     w = jnp.asarray(rng.randn(K, N), jnp.float32)
     tq = TensorQuant(fmt.name, scaler="abfp", group=n)
     pol = QuantPolicy(name="t", input=tq, weight=tq)
-    # the stored layout, codes UNPACKED (the Pallas kernel's representation)
+    # the stored layout as is: INT4 codes stay packed
     ck = st.compress_kernel(w, tq)
-    got = quant_matmul(x, ck.int8_codes(), ck.scale, fmt, n=n,
-                       block_m=kops.fit_block(M),
-                       block_n=kops.fit_block(N), interpret=True)
+    got = quant_matmul(x, ck.codes, ck.scale, fmt, n=n, interpret=True)
     want = sim.qmatmul(x, w, pol)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -190,7 +188,7 @@ def test_quant_matmul_fused_wrapper_padded():
 def test_fused_policy_routes_compressed_kernel(fmt):
     """policy.fused + compressed weights: the compressed backend hands the
     aligned int path to the Pallas stored-codes kernel (packed INT4 codes
-    are unpacked by the ops wrapper)."""
+    are unpacked inside it)."""
     rng = np.random.RandomState(10)
     x = jnp.asarray(rng.randn(8, 128), jnp.float32)
     w = jnp.asarray(rng.randn(128, 64), jnp.float32)
@@ -203,6 +201,142 @@ def test_fused_policy_routes_compressed_kernel(fmt):
     want = sim.qmatmul(x, w, pol.replace(fused=False))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------- stored-codes kernel vs the einsum path
+@pytest.mark.parametrize("n_out", [128, 384])
+@pytest.mark.parametrize("m", [8, 16, 64])
+@pytest.mark.parametrize("k", [256, 200], ids=["K256", "K200-padded"])
+@pytest.mark.parametrize("wfmt", ["int4", "int8"])
+def test_codes_kernel_matches_einsum_path(wfmt, k, m, n_out):
+    """The kernel reads the codes as stored (packed INT4 or int8, padded K
+    too) and, given the einsum path's own x codes and steps, agrees with
+    ``_compressed_group_matmul`` within f32 summation order."""
+    from repro.core.abfp import abfp_quantize
+    from repro.kernels.quant_matmul import quant_matmul_codes
+
+    rng = np.random.RandomState(_seed(wfmt, k, m, n_out))
+    x = jnp.asarray(rng.randn(m, k), jnp.float32)
+    w = jnp.asarray(rng.randn(k, n_out), jnp.float32)
+    tq_x = TensorQuant("int8", scaler="abfp", group=64)
+    pol = QuantPolicy(name="t", input=tq_x,
+                      weight=TensorQuant(wfmt, scaler="abfp", group=64))
+    wk = st.compress_kernel(w, pol.weight)
+    assert wk.packed == (wfmt == "int4") and (wk.pad > 0) == (k == 200)
+    want = np.asarray(sim._compressed_group_matmul(x, wk, pol, site="",
+                                                   in_alpha=None))
+    # the x codes and steps the kernel is given are the einsum path's
+    sd = jnp.dtype(tq_x.scale_dtype)
+    xc, xs, _ = abfp_quantize(jnp.pad(x, ((0, 0), (0, wk.pad))), tq_x.fmt,
+                              n=64, scale_dtype=sd)
+    xc_e, xs_e, _ = abfp_quantize(x, tq_x.fmt, n=64, scale_dtype=sd)
+    assert np.array_equal(np.asarray(xc), np.asarray(xc_e))
+    assert np.array_equal(np.asarray(xs), np.asarray(xs_e))
+    tol = dict(rtol=1e-5, atol=1e-6 * float(np.abs(want).max()))
+    got = quant_matmul_codes(xc.reshape(m, -1), xs, wk.codes, wk.scale,
+                             interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, **tol)
+    # the ops wrapper quantizes x itself, the same way
+    got = kops.quant_matmul_fused(x, wk, tq_x, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, **tol)
+
+
+def test_codes_blocks_follow_the_shape():
+    """Decode rows take one row block and wide weight blocks; prefill rows
+    take 256-row blocks; weight blocks hold up to 8 MiB of codes; the
+    kernel loops over chunks of 8 groups (qwen2-7b widths: wi, wo,
+    lm_head), or of all groups where no chunk of whole lanes divides
+    them."""
+    from repro.kernels.quant_matmul import codes_blocks
+
+    assert codes_blocks(16, 3584, 18944, 64, True) == (16, 512, 3584, 8)
+    assert codes_blocks(16, 18944, 3584, 64, True) == (16, 512, 18944, 8)
+    assert codes_blocks(16, 3584, 152064, 64, True) == (16, 1536, 3584, 8)
+    assert codes_blocks(1024, 3584, 18944, 64, True) == (256, 512, 3584, 8)
+    assert codes_blocks(1024, 18944, 3584, 64, True) == (256, 512, 18944,
+                                                         8)
+    # an N off the lane grid is one block (interpret mode only)
+    assert codes_blocks(8, 128, 48, 64, True) == (8, 48, 128, 2)
+    assert codes_blocks(32, 192, 96, 64, False) == (32, 96, 192, 3)
+
+
+def test_codes_kernel_pads_rows_past_one_block():
+    """More rows than one block pad to a multiple of it, and the padded
+    rows are cut from the output."""
+    rng = np.random.RandomState(11)
+    x = jnp.asarray(rng.randn(300, 128), jnp.float32)
+    w = jnp.asarray(rng.randn(128, 128), jnp.float32)
+    tq = TensorQuant("int4", scaler="abfp", group=64)
+    pol = QuantPolicy(name="t", input=TensorQuant("int8", scaler="abfp",
+                                                  group=64), weight=tq)
+    wk = st.compress_kernel(w, tq)
+    want = np.asarray(sim._compressed_group_matmul(x, wk, pol, site="",
+                                                   in_alpha=None))
+    got = kops.quant_matmul_fused(x, wk, pol.input, interpret=True)
+    assert got.shape == (300, 128)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                               atol=1e-6 * float(np.abs(want).max()))
+
+
+def _as_tpu(monkeypatch):
+    """Make the dispatcher see a TPU backend (its kernels are then only
+    traced, never run, here)."""
+    monkeypatch.setattr(kops, "should_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("case,kernel", [
+    ("tpu", True),
+    ("cpu", False),
+    ("cpu-fused", True),
+    ("tpu-group-mismatch", False),
+    ("tpu-fp8-input", False),
+    ("tpu-static-input", False),
+    ("tpu-weight-only", False),
+    ("tpu-n-off-lanes", False),
+    ("tpu-n32-packed", False),
+])
+def test_codes_kernel_dispatch_rule(case, kernel, monkeypatch):
+    """The kernel takes a compressed site on a TPU when the input is int
+    ABFP at the stored group and the shapes tile; ``policy.fused`` forces
+    it anywhere; every other case keeps the einsum path."""
+    if case.startswith("tpu"):
+        _as_tpu(monkeypatch)
+    n_out = 96 if case == "tpu-n-off-lanes" else 256
+    n = 32 if case == "tpu-n32-packed" else 64
+    w = jnp.asarray(np.random.RandomState(12).randn(128, n_out), jnp.float32)
+    wk = st.compress_kernel(w, TensorQuant("int4", scaler="abfp", group=n))
+    tq_in = {
+        "tpu-group-mismatch": TensorQuant("int8", scaler="abfp", group=32),
+        "tpu-fp8-input": TensorQuant("e4m3", scaler="abfp", group=64),
+        "tpu-static-input": TensorQuant("int8", scaler="static"),
+        "tpu-weight-only": None,
+    }.get(case, TensorQuant("int8", scaler="abfp", group=n))
+    pol = QuantPolicy(name="t", input=tq_in,
+                      weight=TensorQuant("int4", scaler="abfp", group=n),
+                      fused=case == "cpu-fused")
+    assert sim.codes_kernel_takes(wk, pol) == kernel
+
+
+def test_site_tally_counts_kernel_and_fallback_sites(monkeypatch):
+    """Traced on a TPU backend, a tiling int-ABFP site counts as a kernel
+    site and one off the lane grid as a fallback; the tally is of traced
+    sites and closes with its block."""
+    _as_tpu(monkeypatch)
+    rng = np.random.RandomState(13)
+    tq = TensorQuant("int4", scaler="abfp", group=64)
+    pol = QuantPolicy(name="t", input=TensorQuant("int8", scaler="abfp",
+                                                  group=64), weight=tq)
+    tiles = st.compress_kernel(jnp.asarray(rng.randn(128, 256)), tq)
+    ragged = st.compress_kernel(jnp.asarray(rng.randn(128, 96)), tq)
+    x = jax.ShapeDtypeStruct((16, 128), jnp.float32)
+    with sim.compressed_site_tally() as outer:
+        with sim.compressed_site_tally() as inner:
+            y = jax.eval_shape(lambda x: sim.qmatmul(x, tiles, pol), x)
+        jax.eval_shape(lambda x: sim.qmatmul(x, ragged, pol), x)
+    assert y.shape == (16, 256)
+    assert inner == {"qmm_kernel_sites": 1, "qmm_fallback_sites": 0}
+    assert outer == {"qmm_kernel_sites": 1, "qmm_fallback_sites": 1}
+    assert sim._SITE_TALLIES == []
 
 
 # -------------------------------------------------- named-shape ValueErrors

@@ -166,6 +166,41 @@ def test_admission_blocked_on_pages_is_counted(setup):
     assert sum(c["admitted"] for c in admit) == 2
 
 
+def test_engine_counts_compressed_sites_by_contraction(setup):
+    """Each step program's compressed matmul sites ride on its phase's
+    span as ``qmm_kernel_sites`` / ``qmm_fallback_sites``: off the TPU the
+    W4A8 sites (q, k, v, o, wi, wg, wo of the scanned block, and lm_head)
+    take the einsum path; ``policy.fused`` puts all of them on the
+    stored-codes kernel, which emits the same tokens."""
+    cfg, model, params = setup
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, cfg.vocab, n).astype(np.int32)
+               for n in (5, 11, 3)]
+    tokens, sites = {}, {}
+    for fused in (False, True):
+        pol = preset("w4a8_abfp")
+        eng = PagedServeEngine(model, params, n_slots=2, max_len=64,
+                               policy=pol.replace(fused=fused),
+                               page_size=4, prefill_chunk=8, compress=True)
+        done, spans = _run(eng, [Request(uid=i, prompt=p, max_new_tokens=3)
+                                 for i, p in enumerate(prompts)])
+        tokens[fused] = done
+        sites[fused] = {(s.counts["qmm_kernel_sites"],
+                         s.counts["qmm_fallback_sites"])
+                        for s in spans
+                        if s.name in ("serve.prefill", "serve.decode")}
+    assert sites == {False: {(0, 8)}, True: {(8, 0)}}
+    assert tokens[True] == tokens[False]
+    # dense weights have no compressed sites
+    eng = PagedServeEngine(model, params, n_slots=2, max_len=64,
+                           policy=preset("fp32"), page_size=4,
+                           prefill_chunk=8)
+    _, spans = _run(eng, [Request(uid=0, prompt=prompts[0],
+                                  max_new_tokens=2)])
+    assert all(s.counts["qmm_kernel_sites"] == s.counts[
+        "qmm_fallback_sites"] == 0 for s in spans if s.name == "serve.decode")
+
+
 def test_serve_launcher_reports_span_totals(capsys):
     from repro.launch.serve import main
 
