@@ -29,7 +29,6 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import model as bmodel
     from bench.harness import load_cell
     from repro.core.policy import preset, with_attn_backend
     from repro.models import build_model
@@ -44,7 +43,8 @@ def main(argv=None) -> int:
     cell = load_cell(args.workload)
     conf, mix, s = cell.conf, cell.mix, cell.conf["serving"]
     n_slots = mix.get("n_slots", s["n_slots"])
-    cfg = bmodel.arch_config(conf)
+    plain = cell.plain
+    cfg = plain.arch_config(conf)
     model = build_model(cfg)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -62,7 +62,7 @@ def main(argv=None) -> int:
               flush=True)
 
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
-    make = jax.jit(lambda k: bmodel.program_params(bmodel.weights_fn(conf)(k)))
+    make = jax.jit(lambda k: plain.program_params(plain.weights_fn(conf)(k)))
     report("weights", make.lower(key).compile())
     dense = jax.eval_shape(make, key)
     policy = preset(s["policy"], n_layers=cfg.n_layers)

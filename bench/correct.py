@@ -3,28 +3,29 @@
 Once the window has closed and the engine is freed, a sample drawn from
 the seed of the requests the window finished (with a resident mix, whose
 requests outlast the window, also those in flight with their tokens so
-far),
-the longest always among them, is run through the plain reference
-(``bench/reference.py``), teacher-forced over each prompt and its served
-tokens.  At each served position it reads how far the reference's logit
-of the served token lies below the reference's best.  The cell's file
-(``bench/cells/<cell>.json``) names the number compared, the widest such
-gap over the sample (``max_gap``) or their mean (``mean_gap``), with its
-limit and the readings the limit was set from.  The
-served tokens are greedy, so a sound program serves the reference's best
-token up to the rounding the configuration states.
+far), the longest always among them, is run through the plain reference
+of the configuration's architecture (``Reference`` of
+``bench/plain/<name>.py``, judged by ``bench/reference.Judge``),
+teacher-forced over each prompt and its served tokens.  At each served
+position it reads how far the reference's logit of the served token lies
+below the reference's best.  The cell's file (``bench/cells/<cell>.json``)
+names the number compared, the widest such gap over the sample
+(``max_gap``) or their mean (``mean_gap``), with its limit and the
+readings the limit was set from.  The served tokens are greedy, so a
+sound program serves the reference's best token up to the rounding the
+configuration states.
 
 Besides, every request finished in the window must have served exactly
 the tokens it asked for (no end-of-sequence token is set): an exact count
 with the limit 0.
 
 ``control`` (never used by the benchmark's own runs; a lower precision of
-``bench/reference.py``) puts the control in the program's place: at each
-of those positions the token that the reference at that precision puts
-first stands in for the served one, and the same comparison reads its gap
-against the same limit, so the run has to come out not correct.  That is
-how each limit's upper reading is taken; the program's own readings are
-then reported beside it.
+the plain reference, ``bench/reference.py``) puts the control in the
+program's place: at each of those positions the token that the reference
+at that precision puts first stands in for the served one, and the same
+comparison reads its gap against the same limit, so the run has to come
+out not correct.  That is how each limit's upper reading is taken; the
+program's own readings are then reported beside it.
 """
 
 from __future__ import annotations
@@ -34,7 +35,6 @@ import sys
 import numpy as np
 
 from bench import model as bmodel
-from bench.reference import Reference
 
 
 def collect(ticker, engine, t0: float, t1: float, loop: str) -> dict:
@@ -90,7 +90,9 @@ def check(cell, seed: int, served: dict, control: str | None = None
     ``control``, the program's own readings (None without)."""
     lim = cell.check
     picked = sample(served["done"], seed, lim["sample_tokens"])
-    ref = Reference(cell.conf, bmodel.make_weights(cell.conf, seed))
+    plain = cell.plain
+    ref = plain.Reference(cell.conf,
+                          bmodel.make_weights(plain, cell.conf, seed))
     gaps, cgaps = [], []
     for _, prompt, tokens in picked:
         r = ref.judge(prompt, tokens, control=control)

@@ -4,8 +4,15 @@ The harness is driven by data.  A cell names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); its correctness limit sits in
 ``bench/cells/<cell>.json``; every metric is read by
-``bench/metrics/<metric>.py``.  Adding a cell, mix, configuration or
-metric adds files and entries and edits none.
+``bench/metrics/<metric>.py``.  A configuration names its architecture
+(``"plain": "<name>"``), and ``bench/plain/<name>.py`` gives everything
+that knows that architecture's weights, layers and widths: the program's
+``ArchConfig``, the plain weights, their names in the program, the plain
+reference and the count of needed work (``bench/plain/decoder.py`` says
+what each is).  The harness, the check and the trace reduction reach the
+architecture only through that module.  So adding a cell, mix,
+configuration, architecture or metric adds files and entries, and edits
+no file the benchmark already has.
 
 One run, in one process that starts no child:
 
@@ -26,12 +33,15 @@ One run, in one process that starts no child:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
+import re
 import shutil
 import sys
 import time
+import zlib
 from pathlib import Path
 
 import jax
@@ -41,11 +51,11 @@ from bench import correct
 from bench import model as bmodel
 from bench import trace as btrace
 from bench.traffic import Traffic
-from bench.work import WorkCounter
+from bench.work import Need
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
-TRACE_DIR = ROOT / ".bench_out" / "trace"
+TRACE_DIR = Path(".bench_out") / "trace"  # under the cell's checkout
 WARM_UID = 1 << 30  # warm-up requests' uids, clear of the traffic's
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 SLOW_TICK_S = 0.5  # a tick of the cells' mixes takes 20-360 ms
@@ -65,10 +75,12 @@ class Cell:
     name: str
     chips: int
     conf: dict
+    plain: object  # the configuration's bench/plain/<name>.py module
     mix: dict
     check: dict
     end_to_end: list  # metric entries of BENCHMARK.json this cell reports
     per_layer: list
+    root: Path = ROOT  # where bench/metrics/ is read from
 
 
 def _reports(metric: dict, cell: str, e2e_names: set) -> bool:
@@ -86,16 +98,21 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                          f"{[w['name'] for w in bench['workloads']]}")
     conf_entry = next(c for c in bench["configs"]
                       if c["name"] == entry["config"])
+    conf = _json(root / conf_entry["file"])
+    if "plain" not in conf:
+        raise SystemExit(f"{conf_entry['file']} names no architecture: "
+                         'give it "plain": "<name>" of bench/plain/<name>.py')
     e2e = [m for m in bench["end_to_end"] if _reports(m, name, set())]
     names = {m["name"] for m in e2e}
     return Cell(
-        name=name, chips=entry["chips"],
-        conf=_json(root / conf_entry["file"]),
+        name=name, chips=entry["chips"], conf=conf,
+        plain=architecture(conf["plain"], root),
         mix=_json(root / "bench" / "traffic" / f"{entry['traffic']}.json"),
         check=_json(root / "bench" / "cells" / f"{name}.json"),
         end_to_end=e2e,
         per_layer=[m for m in bench["per_layer"]
-                   if _reports(m, name, names)])
+                   if _reports(m, name, names)],
+        root=root)
 
 
 def find_device(chips: int):
@@ -114,14 +131,29 @@ def find_device(chips: int):
             "count": len(devices)}, None
 
 
-def reader(metric: str):
-    """``read`` of ``bench/metrics/<metric>.py``."""
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+@functools.cache
+def _module(path: Path, kind: str):
+    """The file ``path`` as a module, registered (as dataclasses need)
+    under a name of its own."""
+    stem = re.sub(r"\W", "_", path.stem)
+    name = f"bench_{kind}_{stem}_{zlib.crc32(str(path).encode()):08x}"
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def architecture(name: str, root: Path = ROOT):
+    """The module ``root/bench/plain/<name>.py``, loaded once."""
+    return _module((root / "bench" / "plain" / f"{name}.py").resolve(),
+                   "plain")
+
+
+def reader(metric: str, root: Path = ROOT):
+    """``read`` of ``root/bench/metrics/<metric>.py``."""
+    return _module((root / "bench" / "metrics" / f"{metric}.py").resolve(),
+                   "metric").read
 
 
 class Ticker:
@@ -134,7 +166,7 @@ class Ticker:
     its span; the engine's own ``device_get`` follows at once anyway.
     """
 
-    def __init__(self, engine, work: WorkCounter, clock=time.perf_counter):
+    def __init__(self, engine, work: Need, clock=time.perf_counter):
         self.e = engine
         self.work = work
         self.clock = clock
@@ -279,7 +311,7 @@ class Facts:
     stamps: dict
     due: dict  # uid -> host time each request was due
     occupancy: list
-    work: WorkCounter
+    work: Need  # the configuration's counter of needed work
     device: dict | None  # bench/trace.py's reduction; None untraced
 
     @property
@@ -299,18 +331,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     setup: dict = {}
     t = time.perf_counter()
     n_slots = mix.get("n_slots", conf["serving"]["n_slots"])
-    cfg = bmodel.arch_config(conf)
-    params = bmodel.program_params(bmodel.make_weights(conf, seed))
+    plain = cell.plain
+    cfg = plain.arch_config(conf)
+    params = plain.program_params(bmodel.make_weights(plain, conf, seed))
     jax.block_until_ready(params)
     setup["weights_s"], t = time.perf_counter() - t, time.perf_counter()
     engine = bmodel.build_engine(conf, cfg, params, n_slots, mix["max_len"])
     del params
     jax.block_until_ready((engine.params, engine.state))
     setup["engine_s"], t = time.perf_counter() - t, time.perf_counter()
-    ticker = Ticker(engine, WorkCounter(bmodel.shape(conf)))
+    ticker = Ticker(engine, plain.work_counter(conf))
     if fault is not None:
         fault(engine)
-    vocab = conf["model"]["vocab_size"]
+    vocab = cfg.vocab  # the published vocabulary: token ids below it
     traffic = Traffic(mix, seed, vocab, n_slots)
     loop = mix["loop"]
     if loop != "resident":
@@ -327,11 +360,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     jax.monitoring.register_event_duration_secs_listener(
         lambda ev, d, **kw: compiles.append(d) if ev == COMPILE_EVENT
         else None)
+    trace_dir = cell.root / TRACE_DIR
     if traced:
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        shutil.rmtree(trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
-        jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
     # set-up's objects are frozen out of the collector, and the collector
     # stays off in the window, so that no full collection stalls a tick
     gc.collect()
@@ -352,10 +386,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     if traced:
         jax.profiler.stop_trace()
         reduced = btrace.reduce(
-            btrace.load(str(TRACE_DIR)), calls=ticker.calls,
+            btrace.load(str(trace_dir)), calls=ticker.calls,
             step_prefix=ticker.step_name,
             kernel=conf.get("attention_kernel") or "")
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        shutil.rmtree(trace_dir, ignore_errors=True)
     log("set-up seconds: " + ", ".join(f"{k} {v:.3f}"
                                        for k, v in setup.items())
         + f"; total {setup_s:.3f} from process start")
@@ -373,7 +407,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     chosen = cell.per_layer if traced else cell.end_to_end
     metrics = {}
     for m in chosen:
-        value = reader(m["name"])(facts)
+        value = reader(m["name"], cell.root)(facts)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     done = [u for u, st in ticker.stamps.items()

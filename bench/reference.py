@@ -1,14 +1,11 @@
-"""The plain reference: a decoder forward pass in ``jax.numpy``.
+"""What every plain reference shares: the numerics and the judge.
 
-It imports nothing of the program and reads only the benchmark's plain
-weight tree (``bench/model.py``).  It follows the published description of
-a Qwen2 / Granite-3 style decoder: RMSNorm before attention and before the
-MLP, q/k/v projections (with biases where the configuration has them),
-rotary embedding over the two halves of each head (the ``rotate_half``
-convention of the published code), causal grouped-query attention with
-query head ``h`` reading KV head ``h // (H / KV)`` and scale
-``head_dim ** -0.5``, a SwiGLU MLP ``down(silu(gate(x)) * up(x))``, a
-final RMSNorm and the readout (the embedding's transpose where tied).
+A plain reference (``Reference`` of ``bench/plain/<name>.py``) imports
+nothing of the program and reads only the benchmark's plain weight tree.
+It subclasses ``Judge``, which teacher-forces it over a served request
+and reads the gaps; the subclass gives the hidden rows before the final
+norm (``_hidden``) and the final norm's scale and the readout it reads
+them through.
 
 ``precision`` selects how the matmuls and the stream are computed:
 
@@ -24,10 +21,7 @@ final RMSNorm and the readout (the embedding's transpose where tied).
            next precision below a bf16 matmul pass.
 
 Weights are read in float32 (bfloat16 weights widen exactly), or in
-bfloat16 for the ``bf16`` control.  It runs one request at a time, layer by layer, over the prompt and the
-served tokens, with the sequence padded to a power of two of at least
-``block`` rows and attention taken ``block`` query rows at a time, so that
-ten thousand positions fit beside the weights.
+bfloat16 for the ``bf16`` control.
 """
 
 from __future__ import annotations
@@ -90,47 +84,6 @@ def _rope(x, pos, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "precision", "block"))
-def _layer(x, lw, *, m: tuple, precision: str, block: int):
-    """One decoder layer over ``x`` (S, D), S a multiple of ``block``."""
-    H, KV, hd, theta, bias = m
-    S = x.shape[0]
-    pos = jnp.arange(S)
-    h = _rms(x, lw["ln1"], precision)
-    q = _mm(h, lw["wq"], precision)
-    k = _mm(h, lw["wk"], precision)
-    v = _mm(h, lw["wv"], precision)
-    if bias:
-        q, k, v = q + lw["bq"], k + lw["bk"], v + lw["bv"]
-    dt = jnp.bfloat16 if precision == "bf16" else jnp.float32
-    q = _rope(q.reshape(S, H, hd), pos, theta).astype(dt)
-    k = _rope(k.reshape(S, KV, hd), pos, theta).astype(dt)
-    v = v.reshape(S, KV, hd).astype(dt)
-    g = H // KV
-    mmp = None if precision == "bf16" else HIGHEST
-
-    def attend(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * block, block)
-        qb = qb.reshape(block, KV, g, hd)
-        s = jnp.einsum("qkgd,tkd->kgqt", qb, k, precision=mmp,
-                       preferred_element_type=jnp.float32) * hd ** -0.5
-        qpos = i * block + jnp.arange(block)
-        s = jnp.where(pos[None, None, None, :] <= qpos[None, None, :, None],
-                      s, NEG)
-        p = jax.nn.softmax(s, axis=-1).astype(dt)
-        o = jnp.einsum("kgqt,tkd->qkgd", p, v, precision=mmp,
-                       preferred_element_type=jnp.float32)
-        return o.reshape(block, H * hd).astype(dt)
-
-    att = jax.lax.map(attend, jnp.arange(S // block)).reshape(S, H * hd)
-    x = x + _mm(att, lw["wo"], precision).astype(x.dtype)
-    h = _rms(x, lw["ln2"], precision)
-    gate = _mm(h, lw["w_gate"], precision).astype(jnp.float32)
-    up = _mm(h, lw["w_up"], precision).astype(jnp.float32)
-    f = (jax.nn.silu(gate) * up).astype(dt)
-    return x + _mm(f, lw["w_down"], precision).astype(x.dtype)
-
-
 @functools.partial(jax.jit, static_argnames=("vocab", "precision"))
 def _gaps(x, final_norm, head, tokens, *, vocab: int, precision: str):
     """Per row: (reference best logit - logit of ``tokens``, argmax)."""
@@ -140,33 +93,20 @@ def _gaps(x, final_norm, head, tokens, *, vocab: int, precision: str):
     return logits.max(-1) - picked, logits.argmax(-1)
 
 
-class Reference:
-    """Teacher-forced logits of the plain decoder over served requests."""
+class Judge:
+    """Teacher-forced logits of a plain reference over served requests.
 
-    def __init__(self, conf: dict, weights: dict, block: int = 512):
-        m = conf["model"]
-        self.m = (m["num_attention_heads"], m["num_key_value_heads"],
-                  m["head_dim"], float(m["rope_theta"]),
-                  bool(m["attention_bias"]))
-        self.vocab = m["vocab_size"]
-        self.L = m["num_hidden_layers"]
-        self.w = weights
-        self.head = (weights["lm_head"] if "lm_head" in weights
-                     else weights["embed"].T)
-        self.block = block
+    A subclass sets ``vocab`` (the published vocabulary), ``final_norm``
+    (D,) and ``head`` (D, padded vocabulary), and gives ``_hidden(ids,
+    precision)``: the rows before the final norm at each position of
+    ``ids`` (more rows may follow them)."""
+
+    vocab: int
+    final_norm: jax.Array
+    head: jax.Array
 
     def _hidden(self, ids: np.ndarray, precision: str):
-        S = len(ids)
-        # a power of two of rows: few shapes to compile over any lengths
-        Sp = max(self.block, 1 << (S - 1).bit_length())
-        dt = jnp.bfloat16 if precision == "bf16" else jnp.float32
-        x = self.w["embed"][jnp.asarray(np.pad(ids, (0, Sp - S)))].astype(dt)
-        for i in range(self.L):
-            lw = {k: v[i].astype(dt) for k, v in self.w.items()
-                  if k not in ("embed", "final_norm", "lm_head")}
-            x = _layer(x, lw, m=self.m, precision=precision,
-                       block=self.block)
-        return x
+        raise NotImplementedError
 
     def _read(self, x, at: np.ndarray, tokens: np.ndarray,
               precision: str, rows: int = 256):
@@ -179,7 +119,7 @@ class Reference:
             idx = np.pad(at[i:i + rows], (0, rows - n))
             tk = np.pad(tokens[i:i + rows], (0, rows - n))
             g, a = _gaps(x[jnp.asarray(idx)],
-                         self.w["final_norm"].astype(jnp.float32), head,
+                         self.final_norm.astype(jnp.float32), head,
                          jnp.asarray(tk, jnp.int32), vocab=self.vocab,
                          precision=precision)
             gaps.append(np.asarray(g)[:n])

@@ -41,7 +41,8 @@ def _cell(loop="closed", config="qwen2-7b-w4a8"):
     if loop == "resident":
         mix["prompt"] = {"dist": "loguniform", "min": 60, "max": 120}
     return harness.Cell(
-        name="tiny", chips=1, conf=conf, mix=mix,
+        name="tiny", chips=1, conf=conf,
+        plain=harness.architecture(conf["plain"]), mix=mix,
         check={"number": "max_gap", "limit": TINY_LIMIT[config],
                "sample_tokens": 120},
         end_to_end=[m for m in bench["end_to_end"]

@@ -15,6 +15,7 @@ sys.path.insert(0, str(ROOT))
 import pytest  # noqa: E402
 
 from bench import harness, spans  # noqa: E402
+from bench import trace as btrace  # noqa: E402
 from bench.trace import Event, Trace, reduce  # noqa: E402
 
 MS = 1e6  # ns
@@ -62,32 +63,45 @@ def _trace(program=True):
     mods = [Event("XLA Modules", "jit__step_fn(111)", 10 * MS, 30 * MS),
             Event("XLA Modules", "jit__step_fn(222)", 52 * MS, 8 * MS),
             Event("XLA Modules", "jit__step_fn(222)", 72 * MS, 8 * MS)]
-    ops = [Event("XLA Ops", f"%op.{i} = f32[8] op(x)", s * MS, (e - s) * MS)
-           for i, (s, e, _) in enumerate(OPS)]
+    ops = [Event("XLA Ops", f"%op.{i} = f32[8] op(x)", s * MS, (e - s) * MS,
+                 scope)
+           for i, (s, e, scope) in enumerate(OPS)]
     dev = "/device:TPU:0"
-    return (Trace(devices={dev: mods + ops}, host=_host(program)),
-            {dev: [(s * MS, e * MS, op) for s, e, op in OPS]})
+    return Trace(devices={dev: mods + ops}, host=_host(program))
 
 
 def test_scope_path_drops_wrappers_and_the_primitive():
-    assert spans.scope_path(OPS[0][2]) == "block/attn/q"
-    assert spans.scope_path("jit(_step_fn)/sample/sort") == "sample"
-    assert spans.scope_path("jit(_step_fn)/while") == ""
-    assert spans.scope_path("") == ""
+    assert btrace.scope_path(OPS[0][2]) == "block/attn/q"
+    assert btrace.scope_path("jit(_step_fn)/sample/sort") == "sample"
+    assert btrace.scope_path("jit(_step_fn)/while") == ""
+    assert btrace.scope_path("") == ""
 
 
 def test_scope_seconds_by_step_kind():
-    trace, ops = _trace()
-    got = spans.scope_seconds(trace, ops, CALLS, PREFIX)
+    got = btrace.scope_seconds(_trace(), CALLS, PREFIX)
     assert got == {
         "prefill": {"block/attn/q": pytest.approx(0.020),
                     "sample": pytest.approx(0.010)},
         "decode": {"block/attn/gather": pytest.approx(0.004),
                    "sample": pytest.approx(0.006)}}
+    r = reduce(_trace(), calls=CALLS, step_prefix=PREFIX, kernel="op")
+    assert r["scope_s"] == got
+
+
+def test_decode_sample_ms_reads_the_sample_scope_per_decode_run():
+    r = SimpleNamespace(device=reduce(_trace(), calls=CALLS,
+                                      step_prefix=PREFIX, kernel="op"))
+    # the two decode runs hold 57-60 ms under sample/sort and 77-80 ms
+    # under sample/argmax: 6 ms over 2 runs
+    assert harness.reader("decode_sample_ms")(r) == pytest.approx(3.0)
+    r.device["scope_s"]["decode"].pop("sample")
+    assert harness.reader("decode_sample_ms")(r) is None
+    assert harness.reader("decode_sample_ms")(
+        SimpleNamespace(device=None)) is None
 
 
 def test_idle_gaps_take_the_innermost_program_span():
-    trace, _ = _trace()
+    trace = _trace()
     # idle 0-10 (mid 5: serve.admit), 40-52 (mid 46: serve.prepare),
     # 60-72 (mid 66: serve.admit), 80-100 (no tick)
     got = spans.idle_by_label(trace, CALLS, PREFIX, "k")
@@ -98,19 +112,19 @@ def test_idle_gaps_take_the_innermost_program_span():
 
 
 def test_idle_split_gives_each_instant_to_the_innermost_span():
-    trace, _ = _trace()
+    trace = _trace()
     # gaps 0-10, 40-52, 60-72, 80-100 ms walked through the spans of _host
     assert spans.idle_split(trace) == {
         k: pytest.approx(v * 1e-3) for k, v in {
             "between ticks": 19.5, "serve.prepare": 13.0,
             "serve.update": 7.0, "serve.step": 6.0, "serve.readback": 3.0,
             "serve.admit": 2.6, "bench.tick": 1.8, "serve.tick": 1.1}.items()}
-    assert sum(spans.idle_split(_trace(program=False)[0]).values()) == \
+    assert sum(spans.idle_split(_trace(program=False)).values()) == \
         pytest.approx(0.054)
 
 
 def test_op_scopes_read_the_op_metadata_of_a_serialized_trace():
-    cls = spans._xspace_class()
+    cls = btrace._xspace_class()
     space = cls()
     plane = space.planes.add(name="/device:TPU:0")
     for i, name in ((1, "tf_op"), (2, "hlo_category"),
@@ -127,17 +141,18 @@ def test_op_scopes_read_the_op_metadata_of_a_serialized_trace():
                  "closed_call/block/ffn/wi/...gk,gkn->...gn/dot_general:")
     host = space.planes.add(name="/host:CPU")
     host.event_metadata.add(key=1).value.name = "serve.tick"
-    got = spans.op_scopes(space.SerializeToString())
+    got = btrace.op_scopes(space.SerializeToString())
     assert list(got) == ["/device:TPU:0"]
-    assert {k: spans.scope_path(v) for k, v in got["/device:TPU:0"].items()} \
+    assert {k: btrace.scope_path(v)
+            for k, v in got["/device:TPU:0"].items()} \
         == {"%sort.5 = f32[16] sort(x)": "sample",
             "%fusion.1 = f32[8] fusion(y)": "block/ffn/wi"}
 
 
 def test_program_spans_leave_every_other_number_of_the_reduction():
     kw = dict(calls=CALLS, step_prefix=PREFIX, kernel="op")
-    with_program = reduce(_trace(program=True)[0], **kw)
-    without = reduce(_trace(program=False)[0], **kw)
+    with_program = reduce(_trace(program=True), **kw)
+    without = reduce(_trace(program=False), **kw)
     gaps = with_program.pop("idle_gaps"), without.pop("idle_gaps")
     assert with_program == without
     assert [s for _, s in gaps[0]] == [s for _, s in gaps[1]]
@@ -146,7 +161,7 @@ def test_program_spans_leave_every_other_number_of_the_reduction():
 
 
 def test_step_start_lag_pairs_calls_with_executions():
-    trace, _ = _trace()
+    trace = _trace()
     lag = spans.step_start_lag_ms(trace, CALLS, PREFIX)
     assert lag["n"] == 3
     assert lag["min"] == lag["median"] == lag["max"] == pytest.approx(1.0)

@@ -1,6 +1,7 @@
 """The reduction from a profiler trace to the benchmark's device numbers,
 on a small synthetic trace with hand-counted answers."""
 
+import dataclasses
 import sys
 from pathlib import Path
 
@@ -8,7 +9,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 import pytest  # noqa: E402
 
-from bench.trace import Event, Trace, _step_kinds, op_name, reduce  # noqa: E402
+from bench.trace import (Event, Trace, _step_kinds, load,  # noqa: E402
+                         op_kind, op_name, op_seconds, reduce)
 
 MS = 1e6  # ns
 
@@ -101,6 +103,50 @@ def test_breakdown_ops_and_gaps_are_labelled():
     ]
 
 
+def test_op_seconds_cover_every_op_by_step_kind():
+    r = _reduced()
+    want = {"prefill": {"fusion": 0.020, "flash_attention_quant": 0.020},
+            "decode": {"flash_attention_quant": 0.004, "fusion": 0.016},
+            "other": {"scatter": 0.001}}
+    assert set(r["op_s"]) == set(want)
+    for kind, ops in want.items():
+        assert r["op_s"][kind] == pytest.approx(ops)
+    assert r["kernel_s"] == op_seconds(r["op_s"], "flash_attention_quant")
+    assert op_seconds(r["op_s"], "fusion") == pytest.approx(0.036)
+    assert op_seconds(r["op_s"], "absent") == 0.0
+    # no op of this trace carries a named scope
+    assert r["scope_s"] == {} and r["chips"] == 1
+
+
+def test_scope_seconds_follow_each_ops_scope_path():
+    t = _trace()
+    path = "jit(_step_fn)/while/body/closed_call/block/ffn/wi/dot_general:"
+    dev = [dataclasses.replace(e, scope=path)
+           if e.name.startswith("%fusion") else e
+           for e in t.devices["/device:TPU:0"]]
+    r = reduce(Trace(devices={"/device:TPU:0": dev}, host=t.host),
+               calls=["prefill", "decode", "decode"],
+               step_prefix="jit__step_fn(", kernel="flash_attention_quant")
+    # fusion.9 lies outside the window and outside every timed execution
+    assert r["scope_s"] == {"prefill": {"block/ffn/wi": pytest.approx(0.020)},
+                            "decode": {"block/ffn/wi": pytest.approx(0.016)}}
+    assert r["kernel_s"] == pytest.approx(0.024)
+
+
+def test_load_keeps_the_harness_and_program_spans(tmp_path):
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    for name in ("bench.window", "serve.tick", "other.span"):
+        with jax.profiler.TraceAnnotation(name):
+            jax.block_until_ready(jax.numpy.ones(4) + 1)
+    jax.profiler.stop_trace()
+    names = {e.name for e in load(str(tmp_path)).host}
+    assert names == {"bench.window", "serve.tick"}
+
+
 def test_no_device_plane_reads_nothing():
     t = _trace()
     assert reduce(Trace(devices={}, host=t.host), calls=[],
@@ -109,3 +155,6 @@ def test_no_device_plane_reads_nothing():
 
 def test_op_name():
     assert op_name("%fusion.12 = f32[2] fusion(%a)") == "fusion.12"
+    assert op_kind("fusion.12") == "fusion"
+    assert op_kind("quant_matmul_codes.39") == "quant_matmul_codes"
+    assert op_kind("flash_attention_quant") == "flash_attention_quant"
