@@ -30,6 +30,20 @@ SHAPES: dict[str, ShapeSpec] = {
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN scaling of a layer kind's RoPE (``nn.rotary.yarn_freqs``)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None  # None: 0.1 ln(factor) + 1
+
+
+LAYER_KINDS = ("sliding", "full")
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     # --- identity ---------------------------------------------------------
     name: str = "arch"
@@ -54,8 +68,13 @@ class ArchConfig:
     max_position: int = 1 << 20  # learned-pos table size cap
 
     # --- attention variants -------------------------------------------------
-    window: int | None = None  # sliding-window size (SWA)
-    alt_local_global: bool = False  # gemma2: even layers local, odd global
+    window: int | None = None  # the window of every 'sliding' layer (SWA)
+    # per-layer kinds, the pattern repeating over the layers: 'sliding'
+    # (``window``, default RoPE) or 'full' (global, YaRN where ``yarn``
+    # is set); () is every layer 'sliding' where ``window`` is set, else
+    # 'full'.  gemma2: ('sliding', 'full'); Mellum2: 3 x 'sliding', 'full'
+    layer_types: tuple = ()
+    yarn: Yarn | None = None  # YaRN on the 'full' layers' RoPE
     attn_softcap: float | None = None
     final_softcap: float | None = None
     post_norms: bool = False  # gemma2 extra post-block norms
@@ -63,8 +82,7 @@ class ArchConfig:
     # --- MoE ----------------------------------------------------------------
     n_experts: int = 0
     top_k: int = 2
-    capacity_factor: float = 1.25
-    moe_group_tokens: int = 1024
+    norm_topk_prob: bool = False  # renormalise the top-k weights to sum 1
 
     # --- SSM / hybrid ---------------------------------------------------------
     ssm_state: int = 0  # >0 enables mamba blocks
@@ -106,6 +124,25 @@ class ArchConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    def layer_kinds(self) -> list[str]:
+        """Each layer's kind, ``layer_types`` repeated over the layers."""
+        pattern = self.layer_types or (
+            ("sliding",) if self.window else ("full",))
+        bad = set(pattern) - set(LAYER_KINDS)
+        if bad:
+            raise ValueError(f"{self.name}: unknown layer kinds {sorted(bad)}"
+                             f"; known: {list(LAYER_KINDS)}")
+        if "sliding" in pattern and not self.window:
+            raise ValueError(f"{self.name}: 'sliding' layers need a window")
+        return [pattern[i % len(pattern)] for i in range(self.n_layers)]
+
+    @property
+    def uniform_window(self) -> int | None:
+        """The window when every layer slides (a ring cache of that size
+        holds what each layer reads), else None."""
+        return self.window if set(self.layer_kinds()) == {"sliding"} \
+            else None
 
     @property
     def vocab_padded(self) -> int:
@@ -183,14 +220,14 @@ class ArchConfig:
         """Small same-family config for CPU smoke tests."""
         kw = dict(
             name=self.name + "-reduced",
-            n_layers=min(self.n_layers, 3 if self.shared_attn_every else 2),
+            n_layers=min(self.n_layers, 3 if self.shared_attn_every
+                         else max(2, len(self.layer_types))),
             d_model=64,
             n_heads=4,
             n_kv=2,
             head_dim=16,
             d_ff=128,
             vocab=503,  # deliberately non-multiple-of-256: tests padding
-            moe_group_tokens=64,
             ssm_head_dim=16,
             ssm_state=16 if self.ssm_state else 0,
             ssm_chunk=16,
@@ -203,6 +240,7 @@ class ArchConfig:
         )
         if self.family == "moe":
             kw["n_experts"] = 4
+            kw["top_k"] = min(self.top_k, 2)
         if self.family == "hybrid":
             kw["n_layers"] = 3
             kw["shared_attn_every"] = 3

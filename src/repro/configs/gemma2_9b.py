@@ -20,7 +20,7 @@ CONFIG = ArchConfig(
     norm_plus_one=True,
     post_norms=True,
     window=4096,
-    alt_local_global=True,
+    layer_types=("sliding", "full"),
     attn_softcap=50.0,
     final_softcap=30.0,
     tied_embeddings=True,

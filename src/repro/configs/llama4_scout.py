@@ -18,8 +18,6 @@ CONFIG = ArchConfig(
     norm="rms",
     n_experts=16,
     top_k=1,
-    capacity_factor=1.25,
-    moe_group_tokens=1024,
     tied_embeddings=False,
     rope_theta=500000.0,
     remat="dots",

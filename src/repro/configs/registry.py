@@ -14,6 +14,7 @@ _ARCHS = {
     "zamba2-7b": "repro.configs.zamba2_7b",
     "phi3.5-moe-42b-a6.6b": "repro.configs.phi35_moe",
     "llama4-scout-17b-a16e": "repro.configs.llama4_scout",
+    "mellum2-12b-a2.5b": "repro.configs.mellum2_12b_a2_5b",
     "whisper-large-v3": "repro.configs.whisper_large_v3",
     "internvl2-2b": "repro.configs.internvl2_2b",
     "mamba2-130m": "repro.configs.mamba2_130m",

@@ -150,6 +150,36 @@ def _is_compressed(w) -> bool:
     return type(w).__name__ == "CompressedKernel"
 
 
+def codes_contraction(xc, xs, wk) -> jnp.ndarray:
+    """``sum_g xs[..., g] * scale[g] * (xc_g . codes_g)`` in f32, each
+    group's product exact in int32: x's ABFP codes ``(..., G, n)`` and
+    steps ``(..., G)`` (``core.abfp.abfp_quantize``) against a rank-2
+    ``CompressedKernel``'s stored codes."""
+    ws = wk.scale.astype(jnp.float32)  # (G, N)
+    if wk.packed:
+        # each nibble plane meets its half of every group, exact in
+        # int32, so the unpacked codes are never assembled
+        lo, hi = wk.nibbles()
+        partial = sum(
+            jnp.einsum("...gk,gkn->...gn", xh, ch,
+                       preferred_element_type=jnp.int32)
+            for xh, ch in zip(jnp.split(xc, 2, axis=-1), (lo, hi)))
+    else:
+        partial = jnp.einsum("...gk,gkn->...gn", xc, wk.grouped_codes(),
+                             preferred_element_type=jnp.int32)
+    return jnp.einsum(
+        "...gn,...g,gn->...n", partial.astype(jnp.float32),
+        xs.astype(jnp.float32), ws,
+    )
+
+
+def codes_aligned(w, tq) -> bool:
+    """Whether input quantizer ``tq`` gives x codes that meet compressed
+    weight ``w``'s stored codes group for group (int ABFP at its group)."""
+    return (tq is not None and isinstance(tq.fmt, IntFormat)
+            and tq.scaler == "abfp" and tq.group == w.group)
+
+
 def _compressed_group_matmul(x, wk, policy: QuantPolicy, *, site: str,
                              in_alpha, compute_dtype=jnp.float32):
     """Contract pre-quantized weight codes + unit scales directly.
@@ -184,28 +214,13 @@ def _compressed_group_matmul(x, wk, policy: QuantPolicy, *, site: str,
     n = wk.group
     tq = policy.input
 
-    if (tq is not None and isinstance(tq.fmt, IntFormat)
-            and tq.scaler == "abfp" and tq.group == n):
+    if codes_aligned(wk, tq):
         # abfp_quantize zero-pads x along K exactly like the stored codes
         xc, xs, _ = abfp_mod.abfp_quantize(
             x, tq.fmt, axis=-1, n=n,
             scale_dtype=jnp.dtype(tq.scale_dtype),
         )
-        if wk.packed:
-            # each nibble plane meets its half of every group, exact in
-            # int32, so the unpacked codes are never assembled
-            lo, hi = wk.nibbles()
-            partial = sum(
-                jnp.einsum("...gk,gkn->...gn", xh, ch,
-                           preferred_element_type=jnp.int32)
-                for xh, ch in zip(jnp.split(xc, 2, axis=-1), (lo, hi)))
-        else:
-            partial = jnp.einsum("...gk,gkn->...gn", xc, wk.grouped_codes(),
-                                 preferred_element_type=jnp.int32)
-        return jnp.einsum(
-            "...gn,...g,gn->...n", partial.astype(jnp.float32),
-            xs.astype(jnp.float32), ws,
-        )
+        return codes_contraction(xc, xs, wk)
 
     xq = qdq_activation(x, tq, axis=-1, site=site + "/in", alpha=in_alpha)
     # mirror _fp_matmul's activation-operand rounding, then contract in f32
@@ -297,36 +312,43 @@ def compressed_site_tally():
         _SITE_TALLIES.pop()  # blocks nest: the last one opened closes
 
 
-def codes_kernel_takes(w, policy: QuantPolicy) -> bool:
-    """Whether a compressed site contracts in the stored-codes kernel.
+def codes_kernel_takes(w, policy: QuantPolicy, stacked: bool = False
+                       ) -> bool:
+    """Whether a compressed site contracts in a stored-codes kernel:
+    ``quant_matmul_codes`` for one kernel, ``quant_matmul_grouped`` for a
+    ``stacked`` one (an expert stack, ``(E, Kp, N)``).
 
     It does where the input is int ABFP at the stored group (the aligned
     case) and either the backend is a TPU and the shapes tile — N a
     multiple of 128, the stored codes whole groups, a group's stored rows
     whole (32, 128) int8 tiles — or ``policy.fused`` forces the kernel (in
-    interpret mode off the TPU).  Everything else keeps
-    ``_compressed_group_matmul``.
+    interpret mode off the TPU).  Everything else keeps the jnp
+    contraction.
     """
     from repro.kernels import ops as kops  # lazy: pallas import
 
-    tq = policy.input
-    if not (tq is not None and isinstance(tq.fmt, IntFormat)
-            and tq.scaler == "abfp" and tq.group == w.group
-            and w.codes.ndim == 2):
+    if not (codes_aligned(w, policy.input)
+            and w.codes.ndim == (3 if stacked else 2)):
         return False
-    G, N = w.scale.shape
+    G, N = w.scale.shape[-2:]
     per_code = 2 if w.packed else 1
-    tiles = (N % 128 == 0 and w.codes.shape[0] * per_code == G * w.group
+    tiles = (N % 128 == 0 and w.codes.shape[-2] * per_code == G * w.group
              and w.group // per_code % 32 == 0)
     return policy.fused or (not kops.should_interpret() and tiles)
+
+
+def tally_site(kernel: bool) -> None:
+    """Count one traced compressed site in every open
+    ``compressed_site_tally``."""
+    for tally in _SITE_TALLIES:
+        tally["qmm_kernel_sites" if kernel else "qmm_fallback_sites"] += 1
 
 
 @register_backend("compressed", weight_repr="compressed")
 def _compressed_backend(x, w, policy, *, site, in_alpha, compute_dtype):
     """Serve pre-quantized weight codes straight into the contraction."""
     kernel = codes_kernel_takes(w, policy)
-    for tally in _SITE_TALLIES:
-        tally["qmm_kernel_sites" if kernel else "qmm_fallback_sites"] += 1
+    tally_site(kernel)
     if kernel:
         from repro.kernels import ops as kops  # lazy: pallas import
 

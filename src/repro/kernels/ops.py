@@ -201,3 +201,16 @@ def quant_matmul_fused(x, wk, tq_x, interpret: bool | None = None):
         scale_dtype=jnp.dtype(tq_x.scale_dtype), interpret=interpret,
     )
     return y.reshape(*shape[:-1], y.shape[-1])
+
+
+def quant_matmul_grouped_fused(xc, xs, tile_group, tiles_used, wk,
+                               interpret: bool | None = None):
+    """Grouped compressed-domain Pallas dispatch: rows laid out in tiles
+    by expert, as ABFP codes ``(M, G, n)`` and steps ``(M, G)``, against a
+    stacked ``CompressedKernel`` ``(E, Kp, N)`` read as stored
+    (``quant_matmul.quant_matmul_grouped``)."""
+    interpret = should_interpret() if interpret is None else interpret
+    M = xc.shape[0]
+    return _mm_mod.quant_matmul_grouped(
+        xc.reshape(M, -1), xs, tile_group, jnp.reshape(tiles_used, (1,)),
+        wk.codes, wk.scale, interpret=interpret)

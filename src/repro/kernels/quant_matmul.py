@@ -233,14 +233,16 @@ def codes_blocks(m: int, kp: int, n_out: int, n: int,
 
 
 def _codes_kernel(xc_ref, xs_ref, wc_ref, ws_ref, o_ref, acc_ref, *,
-                  n, packed, k_steps):
+                  n, packed, k_steps, k=None):
     """One (bm, bn) output tile: each ABFP group's int8 x int8 product in
     int32, rescaled in f32 by its x step and weight step, summed in VMEM.
 
     A loop over the K step's chunks of ``c`` groups keeps the kernel's
-    code the size of one chunk.
+    code the size of one chunk.  ``k``: the K step (grid axis 2), read by
+    a caller that runs this inside a branch.
     """
-    k = pl.program_id(2)
+    if k is None:
+        k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -369,3 +371,91 @@ def quant_matmul(
                               scale_dtype=scale_dtype)
     return quant_matmul_codes(xc.reshape(M, K), xs, w_codes, w_scales,
                               interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# Grouped: rows laid out by expert against the stacked codes of every expert
+# ---------------------------------------------------------------------------
+def grouped_tile_rows(rows: int, groups: int) -> int:
+    """Rows of one tile of ``quant_matmul_grouped`` for ``rows`` routed
+    rows over ``groups`` experts: 128 where an expert averages that many
+    rows (prefill chunks), else 32, the int8 sublane tile (decode), so
+    that a lightly routed expert pads few rows."""
+    return 128 if rows >= 128 * groups else 32
+
+
+def _grouped_codes_kernel(group_ref, used_ref, *refs, **kw):
+    """``_codes_kernel`` for the tiles in use; the rest do nothing (their
+    blocks repeat the last used tile's, so nothing is fetched for them,
+    and their output block is the last tile, which is never in use)."""
+    k = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _tile():
+        _codes_kernel(*refs, k=k, **kw)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def quant_matmul_grouped(xc: jnp.ndarray, xs: jnp.ndarray,
+                         tile_group: jnp.ndarray, tiles_used: jnp.ndarray,
+                         w_codes: jnp.ndarray, w_scales: jnp.ndarray,
+                         interpret: bool = False) -> jnp.ndarray:
+    """``(M, N)`` f32: each row's ``quant_matmul_codes`` product with the
+    stored codes of its group (expert).
+
+    ``xc`` (M, Kp) int8 x codes and ``xs`` (M, G) their steps, with the
+    rows laid out in tiles of ``M / n_tiles`` rows, each tile of one
+    group: tile t holds rows of group ``tile_group[t]``, and the first
+    ``tiles_used[0]`` tiles are in use (rows past a group's own, up to its
+    tile's end, are zero).  ``w_codes`` (E, Kp, N) int8 or (E, Kp/2, N)
+    packed INT4 and ``w_scales`` (E, G, N): one ``CompressedKernel`` per
+    expert, stacked, read as stored.  ``tile_group`` and ``tiles_used``
+    are scalar-prefetched: the index maps read each tile's group from
+    them, and a tile past the used ones is skipped.  The last tile is
+    never in use (a layout's tiles bound its groups' with one to spare:
+    ``nn.moe.expert_layout``), and rows of unused tiles are left
+    unwritten: the caller reads only rows of used tiles.
+    The grid runs the output columns outermost, so that the tiles of one
+    group read its codes once per column block.
+    """
+    M, Kp = xc.shape
+    E, G, N = w_scales.shape
+    n_tiles = tile_group.shape[0]
+    bm = M // n_tiles
+    n = Kp // G
+    packed = w_codes.dtype == jnp.uint8
+    _, bn, bk, c = codes_blocks(bm, Kp, N, n, packed)
+    k_steps = Kp // bk
+    xs = xs.astype(jnp.float32).reshape(M, G // c, c).transpose(1, 0, 2)
+    ck = bk // (c * n)
+    wb = bk // 2 if packed else bk
+
+    def tile(i, used):  # the tile whose blocks grid step i reads
+        return jnp.minimum(i, jnp.maximum(used[0] - 1, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(N // bn, n_tiles, k_steps),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda j, i, k, g, u: (tile(i, u), k)),
+            pl.BlockSpec((ck, bm, c),
+                         lambda j, i, k, g, u: (k, tile(i, u), 0)),
+            pl.BlockSpec((None, wb, bn),
+                         lambda j, i, k, g, u: (g[tile(i, u)], k, j)),
+            pl.BlockSpec((None, G, bn),
+                         lambda j, i, k, g, u: (g[tile(i, u)], 0, j)),
+        ],
+        out_specs=pl.BlockSpec(
+            (bm, bn),
+            lambda j, i, k, g, u: (jnp.where(i < u[0], i, n_tiles - 1), j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_grouped_codes_kernel, n=n, packed=packed,
+                          k_steps=k_steps),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(tile_group.astype(jnp.int32), tiles_used.astype(jnp.int32), xc, xs,
+      w_codes, w_scales.astype(jnp.float32))

@@ -143,6 +143,29 @@ def quant_matmul_ref(x: jnp.ndarray, w_codes: jnp.ndarray,
     return y
 
 
+def quant_matmul_grouped_ref(x: jnp.ndarray, tile_group: jnp.ndarray,
+                             tiles_used, w_codes: jnp.ndarray,
+                             w_scales: jnp.ndarray, fmt_x: IntFormat,
+                             n: int = 64) -> jnp.ndarray:
+    """Reference grouped stored-codes matmul: ``x`` (M, K) in tiles of
+    ``M / len(tile_group)`` rows, tile t against expert ``tile_group[t]``'s
+    codes (``w_codes`` (E, K or K/2, N), ``w_scales`` (E, G, N)) as
+    ``quant_matmul_ref`` contracts one kernel; rows of tiles past
+    ``tiles_used`` are zero."""
+    tiles = tile_group.shape[0]
+    M, K = x.shape
+    bm = M // tiles
+
+    def one(args):
+        t, g, xt = args
+        y = quant_matmul_ref(xt, w_codes[g], w_scales[g], fmt_x, n)
+        return jnp.where(t < tiles_used, y, 0.0)
+
+    y = jax.lax.map(one, (jnp.arange(tiles), tile_group,
+                          x.reshape(tiles, bm, K)))
+    return y.reshape(M, -1)
+
+
 def flash_attention_quant_ref(qh, k_codes, v_codes, k_scale, v_scale,
                               q_pos, kv_pos, *, causal: bool = True,
                               window=None, probs_fmt: Format | None = None,

@@ -11,6 +11,12 @@ engine: 4 slots, pages gathered to T = max_len = 512, prefill chunks of
 64; the multi-block attention bodies run at T = 4096, past the
 single-block cut-off (2048).  The stored-codes matmul also runs at the
 benchmark engine's 16 decode rows and 16 x 64 prefill rows.
+
+The grouped stored-codes matmul runs at Mellum2-12B-A2.5B's expert widths
+(d_model 2304, expert width 896, 64 experts, top-8) on its benchmark
+engine's rows: 32 slots decoding (256 routed rows in tiles of 32) and a
+32 x 64-row prefill call (16384 routed rows in tiles of 128), with the
+tiles laid out over the experts and some left unused.
 """
 
 from __future__ import annotations
@@ -27,14 +33,18 @@ from repro.kernels import ref
 from repro.kernels.abfp_qdq import abfp_qdq
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ops import flash_attention_quant_gqa
+from repro.core.abfp import abfp_quantize
 from repro.kernels.quant_matmul import (abfp_matmul, abfp_matmul_int8,
-                                        quant_matmul)
+                                        grouped_tile_rows, quant_matmul,
+                                        quant_matmul_grouped)
 from repro.models.serving_transforms import compress_kernel
 
 D_MODEL, D_FF, HEADS, KV_HEADS, HEAD_DIM = 3584, 18944, 28, 4, 128
 VOCAB = 152064
 N_GROUP = 64  # w4a8_abfp's ABFP group
 SLOTS, MAX_LEN, CHUNK = 4, 512, 64
+# Mellum2-12B-A2.5B's experts and its benchmark engine's slots
+EXPERT_D, EXPERT_F, EXPERTS, TOP_K, MOE_SLOTS = 2304, 896, 64, 8, 32
 
 # Kernels and oracles both run at the TPU's default matmul precision (one
 # bf16 MXU pass over f32 operands, f32 accumulation), so the tolerances
@@ -97,6 +107,39 @@ def _quant_matmul_case(m, label, k=D_MODEL, n=D_FF):
                                      interpret=False),
         lambda x, c, s: ref.quant_matmul_ref(x, c, s, INT8, N_GROUP),
         lambda key: _codes_w(key, m, k, n), 1e-3, _MATMUL_WHY)
+
+
+def _grouped_w(key, routed, k, n):
+    """Rows laid out by expert as the MoE layer lays them out for
+    ``routed`` rows: a tile per expert past its rows, some tiles unused;
+    and the experts' stacked INT4 codes, packed as served."""
+    kx, kw = jax.random.split(key)
+    bm = grouped_tile_rows(routed, EXPERTS)
+    tiles = -(-routed // bm) + EXPERTS
+    used = tiles - 3
+    # the experts in order, each over one or more tiles
+    group = jnp.minimum(jnp.arange(tiles) * EXPERTS // used, EXPERTS - 1)
+    ck = compress_kernel(_normal(kw, (EXPERTS, k, n), k ** -0.5),
+                         TensorQuant("int4", scaler="abfp", group=N_GROUP))
+    return (_normal(kx, (tiles * bm, k)), group.astype(jnp.int32),
+            jnp.full((1,), used, jnp.int32), ck.codes, ck.scale)
+
+
+def _grouped(x, group, used, codes, scales):
+    xc, xs, _ = abfp_quantize(x, INT8, axis=-1, n=N_GROUP)
+    y = quant_matmul_grouped(xc.reshape(x.shape[0], -1), xs, group, used,
+                             codes, scales, interpret=False)
+    bm = x.shape[0] // group.shape[0]
+    return jnp.where(jnp.arange(x.shape[0])[:, None] < used[0] * bm, y, 0.0)
+
+
+def _grouped_case(routed, label, k, n):
+    return KernelCase(
+        f"quant_matmul_grouped[{label} rows={routed}]",
+        _grouped,
+        lambda x, g, u, c, s: ref.quant_matmul_grouped_ref(
+            x, g, u[0], c, s, INT8, N_GROUP),
+        lambda key: _grouped_w(key, routed, k, n), 1e-3, _MATMUL_WHY)
 
 
 def _attn_inputs(key, s, t, code_dtype):
@@ -168,6 +211,13 @@ def kernel_cases() -> list[KernelCase]:
             _quant_matmul_case(m, "wi", D_MODEL, D_FF),
             _quant_matmul_case(m, "wo", D_FF, D_MODEL),
             _quant_matmul_case(m, "lm_head", D_MODEL, VOCAB),
+        ]
+    # Mellum2's experts: wi/wg (d_model -> 896) and wo (896 -> d_model)
+    for routed, label in ((MOE_SLOTS * TOP_K, "decode"),
+                          (MOE_SLOTS * CHUNK * TOP_K, "prefill")):
+        cases += [
+            _grouped_case(routed, f"{label} wi", EXPERT_D, EXPERT_F),
+            _grouped_case(routed, f"{label} wo", EXPERT_F, EXPERT_D),
         ]
     cases += [
         KernelCase(

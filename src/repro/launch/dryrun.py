@@ -284,9 +284,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             collectives_rec = {"collectives_full_unrolled":
                                scan_cost["collectives"]}
         else:
-            k = 1
-            if cfg.alt_local_global:
-                k = 2
+            k = max(1, len(cfg.layer_types))
             if cfg.family == "hybrid":
                 k = cfg.shared_attn_every
             periods = cfg.n_layers // k

@@ -229,11 +229,10 @@ def build_engine(args, cfg=None):
             raise SystemExit(expert_non_moe_message(
                 "--expert-precision auto", cfg.name))
         # offline assignment pass: probe routing frequencies on synthetic
-        # prompts (group-size-aligned), hottest E//4 experts -> INT8,
-        # the rest INT4, emitted as a serializable per-expert PolicyMap
+        # prompts, hottest E//4 experts -> INT8, the rest INT4, emitted
+        # as a serializable per-expert PolicyMap
         prng = np.random.RandomState(args.seed + 2)
-        gt = max(1, cfg.moe_group_tokens)
-        probe = [prng.randint(0, cfg.vocab, (1, gt)).astype(np.int32)
+        probe = [prng.randint(0, cfg.vocab, (1, 1024)).astype(np.int32)
                  for _ in range(2)]
         loads = route_frequencies(model, params, probe, policy=policy)
         n_hot = max(1, cfg.n_experts // 4)

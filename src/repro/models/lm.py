@@ -21,6 +21,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.policy import (
@@ -33,9 +34,10 @@ from repro.dist import sharding as shd
 from repro.nn.attention import Attention, KVCache, PagedKVCache
 from repro.nn.ffn import MLP
 from repro.nn.linear import Dense, Embed
-from repro.nn.moe import MoE
+from repro.nn.moe import COUNT_KEYS, MoE
 from repro.nn.module import Box, stack_init, truncated_normal
 from repro.nn.norms import LayerNorm, RMSNorm
+from repro.nn.rotary import yarn_attention_factor, yarn_freqs
 from repro.nn.ssm import Mamba2, SSMCache
 
 GLOBAL_WINDOW = 1 << 30
@@ -62,12 +64,16 @@ class DecodeState(NamedTuple):
 
     Exactly one of kv / ssm / pages is populated: the fixed-slot ring
     buffer, the SSM state, or the paged KV pool (continuous batching).
+    ``stats``: on paged states, the routing counts of the step that made
+    the state, summed over the MoE layers (``nn.moe.Layout.counts``; {}
+    without experts), so the engine reads them with the step's tokens.
     """
 
     kv: Any  # KVCache with leading L dim, or None
     ssm: Any  # SSMCache with leading L dim, or None
     position: jnp.ndarray  # scalar int32 (aligned) or (B,) per-slot
     pages: Any = None  # PagedState, or None
+    stats: Any = None  # dict of int32 scalars, paged states only
 
 
 def _norm(cfg: ArchConfig):
@@ -102,8 +108,7 @@ class TransformerLM:
         c = self.cfg
         return MoE(
             c.d_model, c.d_ff, n_experts=c.n_experts, top_k=c.top_k,
-            capacity_factor=c.capacity_factor,
-            group_tokens=c.moe_group_tokens, act=c.act,
+            norm_topk_prob=c.norm_topk_prob, act=c.act,
             param_dtype=c.param_dtype, dtype=c.dtype, name=name,
         )
 
@@ -170,34 +175,53 @@ class TransformerLM:
             )
         return params
 
-    # ------------------------------------------------------------- windows
-    def layer_windows_py(self):
-        """Python-int per-layer windows (for unrolled paths under jit)."""
+    # ---------------------------------------------------------- layer kinds
+    def layer_params(self) -> dict:
+        """Each layer's attention settings from its kind
+        (``ArchConfig.layer_kinds``), stacked over layers so a scan
+        carries them: ``window`` (L,) int32 (``GLOBAL_WINDOW`` on full
+        layers) and the layer's RoPE, ``freqs`` (L, head_dim/2) and
+        ``scale`` (L,), which multiplies cos and sin (YaRN's attention
+        factor; 1 for default RoPE)."""
         c = self.cfg
-        if c.alt_local_global:
-            return [
-                (c.window or GLOBAL_WINDOW) if i % 2 == 0 else GLOBAL_WINDOW
-                for i in range(c.n_layers)
-            ]
-        if c.window:
-            return [c.window] * c.n_layers
-        return [GLOBAL_WINDOW] * c.n_layers
+        hd = c.head_dim_
+        default = (1.0 / c.rope_theta ** (
+            np.arange(0, hd, 2, dtype=np.float32) / hd)).astype(np.float32)
+        windows, freqs, scales = [], [], []
+        for kind in c.layer_kinds():
+            yarn = c.yarn if kind == "full" else None
+            windows.append(c.window if kind == "sliding" else GLOBAL_WINDOW)
+            if yarn is None:
+                freqs.append(default)
+                scales.append(1.0)
+            else:
+                freqs.append(yarn_freqs(hd, c.rope_theta, yarn.factor,
+                                        yarn.original_max_position,
+                                        yarn.beta_fast, yarn.beta_slow))
+                scales.append(yarn.attention_factor
+                              or yarn_attention_factor(yarn.factor))
+        return {"window": jnp.asarray(windows, jnp.int32),
+                "freqs": jnp.asarray(np.stack(freqs)),
+                "scale": jnp.asarray(scales, jnp.float32)}
 
-    def layer_windows(self, seq_hint: int) -> jnp.ndarray:
-        """Per-layer attention window (traced-friendly int32 array)."""
-        c = self.cfg
-        if c.alt_local_global:
-            base = jnp.arange(c.n_layers)
-            w = jnp.where(base % 2 == 0, c.window or GLOBAL_WINDOW,
-                          GLOBAL_WINDOW)
-        elif c.window:
-            w = jnp.full((c.n_layers,), c.window)
-        else:
-            w = jnp.full((c.n_layers,), GLOBAL_WINDOW)
-        return w.astype(jnp.int32)
+    @staticmethod
+    def _layer(lp: dict, i: int) -> dict:
+        return jax.tree_util.tree_map(lambda a: a[i], lp)
+
+    def _attend(self, attn, method, bp, h, lp, **kw):
+        """``attn.<method>`` with the layer's window and RoPE."""
+        return getattr(attn, method)(bp, h, window=lp["window"],
+                                     rope=(lp["freqs"], lp["scale"]), **kw)
+
+    def _ffn(self, bp, h, policy, name, q=None, valid=None):
+        """The block's MLP or MoE: (output, MoE metrics or None)."""
+        if self.is_moe:
+            return self._moe(f"{name}/ffn").apply(bp, h, policy, q=q,
+                                                  valid=valid)
+        return self._mlp(f"{name}/ffn").apply(bp, h, policy, q=q), None
 
     # --------------------------------------------------------------- blocks
-    def _block_apply(self, bparams, x, positions, window, policy,
+    def _block_apply(self, bparams, x, positions, lp, policy,
                      q=None, name="block", collect_load=False):
         c = self.cfg
         aux = jnp.zeros((), jnp.float32)
@@ -209,22 +233,18 @@ class TransformerLM:
                 bparams["mamba"], h, policy, q=getq("mamba"))
             return (x, aux, load) if collect_load else (x, aux)
         h = _norm(c).apply(bparams["ln1"], x)
-        h = self._attention(f"{name}/attn").apply(
-            bparams["attn"], h, positions=positions, policy=policy,
-            window=window, q=getq("attn"),
-        )
+        h = self._attend(self._attention(f"{name}/attn"), "apply",
+                         bparams["attn"], h, lp, positions=positions,
+                         policy=policy, q=getq("attn"))
         if c.post_norms:
             h = _norm(c).apply(bparams["ln1_post"], h)
         x = x + h
         h = _norm(c).apply(bparams["ln2"], x)
-        if self.is_moe:
-            h, metrics = self._moe(f"{name}/ffn").apply(
-                bparams["ffn"], h, policy, q=getq("ffn"))
+        h, metrics = self._ffn(bparams["ffn"], h, policy, name,
+                               q=getq("ffn"))
+        if metrics is not None:
             aux = aux + metrics["moe_aux_loss"]
             load = metrics["expert_load"]
-        else:
-            h = self._mlp(f"{name}/ffn").apply(bparams["ffn"], h, policy,
-                                               q=getq("ffn"))
         if c.post_norms:
             h = _norm(c).apply(bparams["ln2_post"], h)
         return (x + h, aux, load) if collect_load else (x + h, aux)
@@ -241,27 +261,26 @@ class TransformerLM:
     def _run_blocks(self, params, x, positions, policy, q=None):
         c = self.cfg
         check_scan_compatible(policy, c.scan_layers, c.name)
-        windows = self.layer_windows(x.shape[1])
+        lps = self.layer_params()
         aux0 = jnp.zeros((), jnp.float32)
         if c.scan_layers:
             def body(carry, xs):
                 xc, aux = carry
                 if q is None:
-                    bp, w = xs
+                    bp, lp = xs
                     qs = None
                 else:
-                    bp, w, qs = xs
-                xc, a = self._block_apply(bp, xc, positions, w, policy, qs)
+                    bp, lp, qs = xs
+                xc, a = self._block_apply(bp, xc, positions, lp, policy, qs)
                 return (xc, aux + a), None
 
             body = self._remat(body)
-            xs = (params["blocks"], windows)
+            xs = (params["blocks"], lps)
             if q is not None:
                 xs = xs + (q["blocks"],)
             (x, aux), _ = jax.lax.scan(body, (x, aux0), xs)
             return x, aux
         aux = aux0
-        wl = self.layer_windows_py()
         block_fn_w = None
         if c.remat != "none":
             pol = (jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
@@ -269,16 +288,16 @@ class TransformerLM:
             # name is static (site addressing must survive remat — a
             # layer-indexed PolicyMap resolves per block here too)
             block_fn_w = jax.checkpoint(
-                lambda name, bp, xc, w, qi: self._block_apply(
-                    bp, xc, positions, w, policy, qi, name=name),
+                lambda name, bp, xc, lp, qi: self._block_apply(
+                    bp, xc, positions, lp, policy, qi, name=name),
                 policy=pol, static_argnums=(0,))
         for i, bp in enumerate(params["blocks"]):
             qi = None if q is None else q["blocks"][i]
-            w = jnp.asarray(int(wl[i]), jnp.int32)
+            lp = self._layer(lps, i)
             if c.remat != "none":
-                x, a = block_fn_w(f"blocks.{i}", bp, x, w, qi)
+                x, a = block_fn_w(f"blocks.{i}", bp, x, lp, qi)
             else:
-                x, a = self._block_apply(bp, x, positions, w, policy, qi,
+                x, a = self._block_apply(bp, x, positions, lp, policy, qi,
                                          name=f"blocks.{i}")
             aux = aux + a
         return x, aux
@@ -288,11 +307,12 @@ class TransformerLM:
                      policy: Policy = QuantPolicy()) -> jnp.ndarray:
         """Routed-token counts per expert: ``(n_layers, n_experts)`` f32.
 
-        A lightweight routing-frequency probe for the serve-side expert
-        store (``repro.serve.experts``): runs the block stack forward and
-        collects each MoE block's post-capacity ``expert_load`` metric.
-        Works under scan (loads stack as scan ys) and unrolled; ``tokens``
-        is ``(B, S)`` and loads sum over the whole batch.
+        A routing-frequency probe for the serve-side expert store
+        (``repro.serve.experts``): runs the block stack forward and
+        collects each MoE block's ``expert_load`` (routing is dropless,
+        so each layer's loads sum to ``top_k`` x the tokens).  Works under
+        scan (loads stack as scan ys) and unrolled; ``tokens`` is
+        ``(B, S)`` and loads sum over the whole batch.
         """
         c = self.cfg
         if not self.is_moe:
@@ -300,21 +320,20 @@ class TransformerLM:
                 f"expert_loads: {c.name!r} is not an MoE config")
         check_scan_compatible(policy, c.scan_layers, c.name)
         x, positions = self._embed_in(params, tokens)
-        windows = self.layer_windows(x.shape[1])
+        lps = self.layer_params()
         if c.scan_layers:
             def body(xc, xs):
-                bp, w = xs
-                xn, _, load = self._block_apply(bp, xc, positions, w,
+                bp, lp = xs
+                xn, _, load = self._block_apply(bp, xc, positions, lp,
                                                 policy, collect_load=True)
                 return xn, load
 
-            _, loads = jax.lax.scan(body, x, (params["blocks"], windows))
+            _, loads = jax.lax.scan(body, x, (params["blocks"], lps))
             return loads
-        wl = self.layer_windows_py()
         loads = []
         for i, bp in enumerate(params["blocks"]):
-            w = jnp.asarray(int(wl[i]), jnp.int32)
-            x, _, load = self._block_apply(bp, x, positions, w, policy,
+            x, _, load = self._block_apply(bp, x, positions,
+                                           self._layer(lps, i), policy,
                                            name=f"blocks.{i}",
                                            collect_load=True)
             loads.append(load)
@@ -404,11 +423,12 @@ class TransformerLM:
         x, positions = self._embed_in(params, tokens, prefix_embeds)
         B, S = x.shape[0], x.shape[1]
         max_len = max_len or S
-        windows = self.layer_windows(S)
-        eff_window = c.window if (c.window and not c.alt_local_global) \
-            else None
+        lps = self.layer_params()
+        eff_window = c.uniform_window
         cache_size = max_len if eff_window is None \
             else min(max_len, eff_window)
+        valid = None if n_valid is None else (
+            jnp.arange(S, dtype=jnp.int32)[None] < n_valid[:, None])
 
         if self.is_ssm:
             def body(carry, xs, name="block"):
@@ -432,33 +452,29 @@ class TransformerLM:
         else:
             def body(carry, xs, name="block"):
                 xc = carry
-                bp, w = xs
+                bp, lp = xs
                 attn_l = self._attention(f"{name}/attn")
                 h = _norm(c).apply(bp["ln1"], xc)
-                h, (kf, vf) = attn_l.apply(
-                    bp["attn"], h, positions=positions, policy=policy,
-                    window=w, return_kv=True, n_valid=n_valid,
+                h, (kf, vf) = self._attend(
+                    attn_l, "apply", bp["attn"], h, lp, positions=positions,
+                    policy=policy, return_kv=True, n_valid=n_valid,
                 )
                 cache = attn_l.fill_cache(kf, vf, cache_size, policy=policy)
                 if c.post_norms:
                     h = _norm(c).apply(bp["ln1_post"], h)
                 xc = xc + h
                 h = _norm(c).apply(bp["ln2"], xc)
-                if self.is_moe:
-                    h, _ = self._moe(f"{name}/ffn").apply(bp["ffn"], h, policy)
-                else:
-                    h = self._mlp(f"{name}/ffn").apply(bp["ffn"], h, policy)
+                h, _ = self._ffn(bp["ffn"], h, policy, name, valid=valid)
                 if c.post_norms:
                     h = _norm(c).apply(bp["ln2_post"], h)
                 return xc + h, cache
 
             if c.scan_layers:
-                x, kv = jax.lax.scan(body, x, (params["blocks"], windows))
+                x, kv = jax.lax.scan(body, x, (params["blocks"], lps))
             else:
                 caches = []
-                wl = self.layer_windows_py()
                 for i, bp in enumerate(params["blocks"]):
-                    x, cc = body(x, (bp, jnp.asarray(int(wl[i]), jnp.int32)),
+                    x, cc = body(x, (bp, self._layer(lps, i)),
                                  name=f"blocks.{i}")
                     caches.append(cc)
                 kv = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *caches)
@@ -489,8 +505,7 @@ class TransformerLM:
         else:
             attn = self._attention()
             # all layers share the ring-buffer size policy: SWA truncates
-            eff_window = c.window if (c.window and not c.alt_local_global) \
-                else None
+            eff_window = c.uniform_window
             one = attn.init_cache(batch, max_len, dtype=c.dtype,
                                   window=eff_window, quantized=kv_quant)
             kv = jax.tree_util.tree_map(
@@ -506,7 +521,7 @@ class TransformerLM:
         check_scan_compatible(policy, c.scan_layers, c.name)
         x, _ = self._embed_in(params, token, pos_offset=state.position)
         pos = state.position
-        windows = self.layer_windows(0)
+        lps = self.layer_params()
 
         if self.is_ssm:
             def body(xc, xs, name="block"):
@@ -530,40 +545,34 @@ class TransformerLM:
             new_state = DecodeState(kv=None, ssm=new_ssm, position=pos + 1)
         else:
             def body(xc, xs, name="block"):
-                bp, cache, w = xs
+                bp, cache, lp = xs
                 h = _norm(c).apply(bp["ln1"], xc)
                 attn = self._attention(f"{name}/attn")
-                h, cache = attn.decode_step(
-                    bp["attn"], h, cache, position=pos, policy=policy,
-                    window=w,
+                h, cache = self._attend(
+                    attn, "decode_step", bp["attn"], h, lp, cache=cache,
+                    position=pos, policy=policy,
                 )
                 if c.post_norms:
                     h = _norm(c).apply(bp["ln1_post"], h)
                 xc = xc + h
                 h = _norm(c).apply(bp["ln2"], xc)
-                if self.is_moe:
-                    h, _ = self._moe(f"{name}/ffn").apply(bp["ffn"], h, policy)
-                else:
-                    h = self._mlp(f"{name}/ffn").apply(bp["ffn"], h, policy)
+                h, _ = self._ffn(bp["ffn"], h, policy, name)
                 if c.post_norms:
                     h = _norm(c).apply(bp["ln2_post"], h)
                 return xc + h, cache
 
             if c.scan_layers:
                 def scan_body(xc, xs):
-                    bp, cache, w = xs
-                    return body(xc, (bp, cache, w))
+                    return body(xc, xs)
                 x, new_kv = jax.lax.scan(
-                    scan_body, x, (params["blocks"], state.kv, windows))
+                    scan_body, x, (params["blocks"], state.kv, lps))
             else:
                 caches = []
-                wl = self.layer_windows_py()
                 for i, bp in enumerate(params["blocks"]):
                     ci = jax.tree_util.tree_map(lambda a: a[i], state.kv)
                     ci = KVCache(*ci)
-                    x, cnew = body(
-                        x, (bp, ci, jnp.asarray(int(wl[i]), jnp.int32)),
-                        name=f"blocks.{i}")
+                    x, cnew = body(x, (bp, ci, self._layer(lps, i)),
+                                   name=f"blocks.{i}")
                     caches.append(cnew)
                 new_kv = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
                                                 *caches)
@@ -595,43 +604,37 @@ class TransformerLM:
         n_valid = jnp.asarray(n_valid, jnp.int32)
         pos = jnp.asarray(state.position, jnp.int32)
         x, _ = self._embed_in(params, tokens, pos_offset=pos)
-        windows = self.layer_windows(0)
+        lps = self.layer_params()
+        valid = (jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]
+                 < n_valid[:, None])
 
         def body(xc, xs, name="block"):
-            bp, cache, w = xs
+            bp, cache, lp = xs
             h = _norm(c).apply(bp["ln1"], xc)
             attn = self._attention(f"{name}/attn")
-            h, cache = attn.chunk_step(
-                bp["attn"], h, cache, position=pos, n_valid=n_valid,
-                policy=policy, window=w,
+            h, cache = self._attend(
+                attn, "chunk_step", bp["attn"], h, lp, cache=cache,
+                position=pos, n_valid=n_valid, policy=policy,
             )
             if c.post_norms:
                 h = _norm(c).apply(bp["ln1_post"], h)
             xc = xc + h
             h = _norm(c).apply(bp["ln2"], xc)
-            if self.is_moe:
-                h, _ = self._moe(f"{name}/ffn").apply(bp["ffn"], h, policy)
-            else:
-                h = self._mlp(f"{name}/ffn").apply(bp["ffn"], h, policy)
+            h, _ = self._ffn(bp["ffn"], h, policy, name, valid=valid)
             if c.post_norms:
                 h = _norm(c).apply(bp["ln2_post"], h)
             return xc + h, cache
 
         if c.scan_layers:
-            def scan_body(xc, xs):
-                bp, cache, w = xs
-                return body(xc, (bp, cache, w))
             x, new_kv = jax.lax.scan(
-                scan_body, x, (params["blocks"], state.kv, windows))
+                body, x, (params["blocks"], state.kv, lps))
         else:
             caches = []
-            wl = self.layer_windows_py()
             for i, bp in enumerate(params["blocks"]):
                 ci = jax.tree_util.tree_map(lambda a: a[i], state.kv)
                 ci = KVCache(*ci)
-                x, cnew = body(
-                    x, (bp, ci, jnp.asarray(int(wl[i]), jnp.int32)),
-                    name=f"blocks.{i}")
+                x, cnew = body(x, (bp, ci, self._layer(lps, i)),
+                               name=f"blocks.{i}")
                 caches.append(cnew)
             new_kv = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
                                             *caches)
@@ -662,10 +665,12 @@ class TransformerLM:
             lambda a: jnp.broadcast_to(a[None], (L,) + a.shape), one
         )
         table = jnp.full((batch, max_pages_per_seq), -1, jnp.int32)
+        stats = ({k: jnp.zeros((), jnp.int32) for k in COUNT_KEYS}
+                 if self.is_moe else {})
         return DecodeState(
             kv=None, ssm=None,
             position=jnp.zeros((batch,), jnp.int32),
-            pages=PagedState(cache=cache, table=table),
+            pages=PagedState(cache=cache, table=table), stats=stats,
         )
 
     def paged_step(self, params, tokens, state: DecodeState, *,
@@ -678,7 +683,9 @@ class TransformerLM:
         ``n_valid = 0``).  Writes the chunk's K/V into the pages mapped by
         ``state.pages.table``, attends over each row's gathered pages and
         returns (logits at each row's last valid token, new state) with
-        ``position`` advanced by ``n_valid``.
+        ``position`` advanced by ``n_valid``, and the step's routing
+        counts as its ``stats``.  Tokens past a row's ``n_valid`` route to
+        no expert.
 
         ``all_logits``: return logits at EVERY chunk position (B, S,
         vocab) instead of the last valid one — the speculative verify
@@ -694,65 +701,60 @@ class TransformerLM:
         table = state.pages.table
         x, _ = self._embed_in(params, tokens, pos_offset=pos)
         B, S = tokens.shape[0], tokens.shape[1]
-        windows = self.layer_windows(0)
+        lps = self.layer_params()
+        valid = jnp.arange(S, dtype=jnp.int32)[None] < n_valid[:, None]
 
         def body(xc, xs, name="block"):
-            bp, cache, w = xs
+            bp, cache, lp = xs
             h = _norm(c).apply(bp["ln1"], xc)
             attn = self._attention(f"{name}/attn")
-            h, cache = attn.paged_step(
-                bp["attn"], h, cache, page_table=table, position=pos,
-                n_valid=n_valid, policy=policy, window=w,
+            h, cache = self._attend(
+                attn, "paged_step", bp["attn"], h, lp, cache=cache,
+                page_table=table, position=pos, n_valid=n_valid,
+                policy=policy,
             )
             if c.post_norms:
                 h = _norm(c).apply(bp["ln1_post"], h)
             xc = xc + h
             h = _norm(c).apply(bp["ln2"], xc)
-            if self.is_moe:
-                h, _ = self._moe(f"{name}/ffn").apply(bp["ffn"], h, policy)
-            else:
-                h = self._mlp(f"{name}/ffn").apply(bp["ffn"], h, policy)
+            h, metrics = self._ffn(bp["ffn"], h, policy, name, valid=valid)
             if c.post_norms:
                 h = _norm(c).apply(bp["ln2_post"], h)
-            return xc + h, cache
+            return xc + h, (cache, {} if metrics is None
+                            else metrics["counts"])
 
         if c.scan_layers:
-            def scan_body(xc, xs):
-                bp, cache, w = xs
-                return body(xc, (bp, cache, w))
-            x, new_cache = jax.lax.scan(
-                scan_body, x,
-                (params["blocks"], state.pages.cache, windows))
+            x, (new_cache, layer_counts) = jax.lax.scan(
+                body, x, (params["blocks"], state.pages.cache, lps))
         else:
-            caches = []
-            wl = self.layer_windows_py()
+            outs = []
             for i, bp in enumerate(params["blocks"]):
                 ci = jax.tree_util.tree_map(
                     lambda a, i=i: a[i], state.pages.cache)
                 ci = PagedKVCache(*ci)
-                x, cnew = body(
-                    x, (bp, ci, jnp.asarray(int(wl[i]), jnp.int32)),
-                    name=f"blocks.{i}")
-                caches.append(cnew)
-            new_cache = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
-                                               *caches)
+                x, out = body(x, (bp, ci, self._layer(lps, i)),
+                              name=f"blocks.{i}")
+                outs.append(out)
+            new_cache, layer_counts = jax.tree_util.tree_map(
+                lambda *a: jnp.stack(a), *outs)
 
         new_state = DecodeState(
             kv=None, ssm=None, position=pos + n_valid,
             pages=PagedState(cache=new_cache, table=table),
+            stats=jax.tree_util.tree_map(lambda a: a.sum(0), layer_counts),
         )
         if all_logits:
             x = _norm(c).apply(params["final_norm"], x)
             with jax.named_scope("readout"):
                 logits = self.head_logits(params, x, policy)
-            return logits, new_state
-        sel = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        x = jnp.take_along_axis(
-            x, jnp.broadcast_to(sel, (B, 1, x.shape[-1])), axis=1)
-        x = _norm(c).apply(params["final_norm"], x)
-        with jax.named_scope("readout"):
-            logits = self.head_logits(params, x, policy)
-        return logits[:, 0], new_state
+        else:
+            sel = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            x = jnp.take_along_axis(
+                x, jnp.broadcast_to(sel, (B, 1, x.shape[-1])), axis=1)
+            x = _norm(c).apply(params["final_norm"], x)
+            with jax.named_scope("readout"):
+                logits = self.head_logits(params, x, policy)[:, 0]
+        return logits, new_state
 
 
 def _sinusoid(S: int, d: int, offset=0) -> jnp.ndarray:

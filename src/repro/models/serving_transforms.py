@@ -42,9 +42,11 @@ QDQs the readout matmul).
 MoE expert banks (the ``wi``/``wg``/``wo`` stacks next to a ``router``)
 are walked along their stacked expert axis: each expert resolves its OWN
 rule at ``{site}/experts.{e}`` (first-match-wins over the block-level
-pattern), so a mixed map can keep hot experts at INT8/FP8 while cold
-experts compress to INT4.  Heterogeneous per-expert storage lives in an
-``ExpertBank`` — the per-expert container the serve-side expert store
+pattern).  A bank whose experts all resolve to one integer rule is stored
+as ONE stacked ``CompressedKernel`` (codes ``(E, Kp, N)``), which the MoE
+layer contracts in one grouped kernel; a mixed map (hot experts at
+INT8/FP8, cold ones at INT4) gives an ``ExpertBank`` of per-expert
+entries — the container the serve-side expert store
 (``repro.serve.experts``) caches into.
 """
 
@@ -112,6 +114,13 @@ class CompressedKernel:
         """Stored group length n (in codes, not bytes — packing-aware)."""
         rows = self.codes.shape[-2] * (2 if self.packed else 1)
         return rows // self.scale.shape[-2]
+
+    def index(self, i) -> "CompressedKernel":
+        """The kernel at index ``i`` (static or traced) of the leading
+        stack dim: one expert of a stacked expert bank."""
+        return CompressedKernel(self.codes[i], self.scale[i], self.pad,
+                                self.k, self.dtype, self.fmt_name,
+                                self.packed)
 
     def nibbles(self):
         """Packed codes -> ``(low, high)`` int8, each ``(..., G, n//2, N)``:
@@ -181,6 +190,17 @@ class ExpertBank:
         n_c = sum(isinstance(e, CompressedKernel) for e in self.entries)
         return (f"ExpertBank(n_experts={self.n_experts}, "
                 f"compressed={n_c}, dense={self.n_experts - n_c})")
+
+
+def expert_entries(bank) -> list:
+    """The per-expert entries of an expert stack: an ``ExpertBank``'s, a
+    stacked ``CompressedKernel``'s experts, or a dense stack's slices."""
+    if isinstance(bank, ExpertBank):
+        return list(bank.entries)
+    if isinstance(bank, CompressedKernel):
+        return [bank.index(e) for e in range(bank.codes.shape[-3])]
+    return [jnp.take(bank, e, axis=bank.ndim - 3)
+            for e in range(bank.shape[bank.ndim - 3])]
 
 
 def entry_bytes(entry) -> int:
@@ -423,9 +443,11 @@ def compress_weights(params, policy: Policy):
         consumed directly by the ``compressed`` execution backend;
       * float-format rule (e.g. FP8-E4M3) — QDQ'd offline, stays dense;
       * fp32 (disabled) rule — untouched.
-    MoE expert stacks become ``ExpertBank``s of per-expert entries, each
-    resolved at ``{site}/experts.{e}`` — a fully fp32 bank stays a plain
-    dense stack.  Pair with ``serving_policy(policy)`` at runtime.
+    MoE expert stacks resolve each expert at ``{site}/experts.{e}``: one
+    integer ABFP rule for all of them gives one stacked
+    ``CompressedKernel``, mixed rules an ``ExpertBank`` of per-expert
+    entries, and a fully fp32 bank stays a plain dense stack.  Pair with
+    ``serving_policy(policy)`` at runtime.
     """
     _check_site_rules_supported(params, policy, "compress_weights")
 
@@ -445,12 +467,16 @@ def compress_weights(params, policy: Policy):
         return _one_entry(w, _site_weight(policy, site))
 
     def one_bank(site, kind, w):
-        if isinstance(w, ExpertBank):
+        if isinstance(w, (ExpertBank, CompressedKernel)):
             return w
         e_axis = w.ndim - 3
         tqs = _expert_weights(policy, site, w.shape[e_axis])
         if all(tq is None for tq in tqs):
             return w  # fully fp32 bank: stays a plain dense stack
+        if (len(set(tqs)) == 1 and isinstance(tqs[0].fmt, IntFormat)
+                and tqs[0].scaler == "abfp"):
+            # one integer rule for every expert: one stacked kernel
+            return _compress_kernel_fused(w, tqs[0])
         return ExpertBank([
             _one_entry(jnp.take(w, e, axis=e_axis), tq)
             for e, tq in enumerate(tqs)
@@ -574,9 +600,7 @@ def weight_bytes_report(dense_params, served_params) -> dict:
 
     def one_bank(site, kind, w):
         dense_w = dense_by_site[(site, kind)]
-        entries = (list(w.entries) if isinstance(w, ExpertBank)
-                   else [jnp.take(w, e, axis=w.ndim - 3)
-                         for e in range(w.shape[w.ndim - 3])])
+        entries = expert_entries(w)
         per_dense = _leaf_bytes(dense_w) // len(entries)
         for e, entry in enumerate(entries):
             if isinstance(entry, CompressedKernel):

@@ -178,7 +178,11 @@ class Attention:
             name=f"{self.name}/{which}",
         )
 
-    def _project_qkv(self, params, x, positions, policy, q=None):
+    def _project_qkv(self, params, x, positions, policy, q=None,
+                     rope=None):
+        """q, k, v heads; ``rope``: the layer's ``(freqs, scale)``
+        (``nn.rotary.apply_rope``), or None for default RoPE at
+        ``rope_theta``."""
         B, S, _ = x.shape
         qh = self._dense("q", self.n_heads * self.head_dim).apply(
             params["q"], x, policy, q=None if q is None else q.get("q")
@@ -193,8 +197,11 @@ class Attention:
         kh = kh.reshape(B, S, self.n_kv, self.head_dim)
         vh = vh.reshape(B, S, self.n_kv, self.head_dim)
         if self.use_rope:
-            qh = apply_rope(qh, positions, self.rope_theta)
-            kh = apply_rope(kh, positions, self.rope_theta)
+            freqs, scale = (None, None) if rope is None else rope
+            qh = apply_rope(qh, positions, self.rope_theta, freqs=freqs,
+                            scale=scale)
+            kh = apply_rope(kh, positions, self.rope_theta, freqs=freqs,
+                            scale=scale)
         qh = shd.constrain(qh, ("batch", "seq", "heads", "head_dim"))
         kh = shd.constrain(kh, ("batch", "seq", "kv_heads", "head_dim"))
         vh = shd.constrain(vh, ("batch", "seq", "kv_heads", "head_dim"))
@@ -400,6 +407,7 @@ class Attention:
         kv_override: tuple | None = None,  # (k, v, kv_positions) for cross
         return_kv: bool = False,
         n_valid: jnp.ndarray | None = None,  # (B,) valid prefix lengths
+        rope=None,  # the layer's (freqs, scale); None: default RoPE
     ) -> jnp.ndarray:
         """Full-sequence attention (training / prefill).
 
@@ -418,7 +426,8 @@ class Attention:
         """
         pol = resolve_policy(policy, self.name)
         B, S, _ = x.shape
-        qh, kh, vh = self._project_qkv(params, x, positions, policy, q)
+        qh, kh, vh = self._project_qkv(params, x, positions, policy, q,
+                                       rope)
         if n_valid is not None:
             keep = (jnp.arange(S, dtype=jnp.int32)[None, :]
                     < n_valid[:, None])[..., None, None]
@@ -562,6 +571,7 @@ class Attention:
         policy: Policy,
         window=None,
         q: dict | None = None,
+        rope=None,
     ) -> tuple[jnp.ndarray, KVCache]:
         pol = resolve_policy(policy, self.name)
         B = x.shape[0]
@@ -569,7 +579,7 @@ class Attention:
         aligned = position.ndim == 0  # all rows at the same position
         pos_vec = jnp.broadcast_to(jnp.atleast_1d(position), (B,))
         pos_b = pos_vec[:, None]  # (B, 1) query positions
-        qh, kh, vh = self._project_qkv(params, x, pos_b, policy, q)
+        qh, kh, vh = self._project_qkv(params, x, pos_b, policy, q, rope)
         int8_cache = cache.k_scale is not None
         kv_on_write = (pol.enabled and pol.attn_bmm
                        and pol.input is not None
@@ -676,6 +686,7 @@ class Attention:
         policy: Policy,
         window=None,
         q: dict | None = None,
+        rope=None,
     ) -> tuple[jnp.ndarray, KVCache]:
         """Write-then-attend over an S-token chunk against the ring buffer.
 
@@ -703,7 +714,8 @@ class Attention:
         pos_vec = jnp.broadcast_to(jnp.atleast_1d(position), (B,))
         n_valid = jnp.asarray(n_valid, jnp.int32)
         positions = pos_vec[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
-        qh, kh, vh = self._project_qkv(params, x, positions, policy, q)
+        qh, kh, vh = self._project_qkv(params, x, positions, policy, q,
+                                       rope)
         int8_cache = cache.k_scale is not None
         kv_on_write = (pol.enabled and pol.attn_bmm
                        and pol.input is not None
@@ -905,6 +917,7 @@ class Attention:
         policy: Policy,
         window=None,
         q: dict | None = None,
+        rope=None,
     ) -> tuple[jnp.ndarray, PagedKVCache]:
         """Unified paged write-then-attend over a token chunk.
 
@@ -928,7 +941,8 @@ class Attention:
         position = jnp.asarray(position, jnp.int32)
         n_valid = jnp.asarray(n_valid, jnp.int32)
         positions = position[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
-        qh, kh, vh = self._project_qkv(params, x, positions, policy, q)
+        qh, kh, vh = self._project_qkv(params, x, positions, policy, q,
+                                       rope)
         keep = (jnp.arange(S, dtype=jnp.int32)[None] < n_valid[:, None])
         kh = kh * keep[..., None, None].astype(kh.dtype)
         vh = vh * keep[..., None, None].astype(vh.dtype)

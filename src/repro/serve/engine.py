@@ -38,9 +38,9 @@ drops its weight quantizers; qmatmul's ``compressed`` execution backend
 then contracts the stored codes directly, so decode never dequantizes a
 kernel.  ``engine.weight_bytes`` records the resident-byte accounting.
 
-Expert-resident MoE serving: when ``compress=True`` meets an MoE model,
-the per-expert compressed banks are collected into a
-``serve.experts.ExpertStore`` — an LRU (``expert_cache`` capacity) of
+Expert-resident MoE serving: when ``compress=True`` meets an MoE model
+and an ``expert_cache`` capacity is given, the compressed expert banks
+are collected into a ``serve.experts.ExpertStore`` — an LRU of
 decompressed-dense expert copies fed by a routing-frequency probe at
 admission.  ``refresh_experts()`` swaps cache-resident experts into the
 params (skipping their per-step dequant); cache state is pure
@@ -66,6 +66,9 @@ from repro.serve import tracing
 from repro.serve.kv_pages import (PageGeometry, PagePool,
                                   attention_read_bytes, check_geometry,
                                   pages_for, resident_kv_bytes)
+
+
+PROBE_BUCKET = 64  # the expert store's routing probe pads prompts to it
 
 
 @dataclasses.dataclass
@@ -136,7 +139,7 @@ class _EngineBase:
         self.queue: list[Request] = []
         self.done: list[Completion] = []
         self.ticks = 0
-        self._expert_probe_cache = {}  # jitted expert_loads per padded len
+        self._expert_probe_cache = {}  # jitted expert_loads per bucket
 
     # ------------------------------------------------------- expert store
     def _build_expert_store(self, served, expert_cache: int | None,
@@ -155,7 +158,8 @@ class _EngineBase:
                 raise ValueError(expert_non_moe_message(
                     "an expert cache",
                     getattr(self.model.cfg, "name", "?")))
-        if compress and getattr(self.model, "is_moe", False):
+        if expert_cache is not None and getattr(self.model, "is_moe",
+                                                False):
             from repro.serve.experts import ExpertStore
 
             try:
@@ -171,17 +175,15 @@ class _EngineBase:
     def _observe_experts(self, prompt) -> None:
         """Probe routing loads for an admitted prompt and feed the store.
 
-        The probe pads the prompt to a multiple of the MoE group size
-        (the dispatch asserts ``(B*S) % group_tokens == 0``) — pad-token
-        routes only perturb the frequency counters, and counters/cache
-        state never enter the compute path, so tokens are unaffected."""
+        The probe pads the prompt to a multiple of ``PROBE_BUCKET``
+        tokens, one compile per bucket — pad-token routes only perturb
+        the frequency counters, and counters/cache state never enter the
+        compute path, so tokens are unaffected."""
         if self.expert_store is None:
             return
         p = np.asarray(prompt, np.int32).reshape(-1)
-        gt = max(1, getattr(self.model.cfg, "moe_group_tokens", 1))
-        padded = max(gt, -(-len(p) // gt) * gt)
-        if padded != len(p):
-            p = np.concatenate([p, np.zeros(padded - len(p), np.int32)])
+        padded = -(-len(p) // PROBE_BUCKET) * PROBE_BUCKET
+        p = np.concatenate([p, np.zeros(padded - len(p), np.int32)])
         fn = self._expert_probe_cache.get(padded)
         if fn is None:
             fn = jax.jit(lambda params, tokens: self.model.expert_loads(
@@ -655,7 +657,10 @@ class PagedServeEngine(_EngineBase):
                 tok, state, self._keys = self._step(*args)
             counts.update(self._sites["prefill"])
             with tracing.span("serve.readback"):
-                toks = np.asarray(jax.device_get(tok)).reshape(-1)
+                # the step's routing counts come back with its tokens
+                toks, routed = jax.device_get((tok, state.stats))
+                toks = np.asarray(toks).reshape(-1)
+            counts.update({k: int(v) for k, v in routed.items()})
             with tracing.span("serve.update"):
                 self.state = state
                 for s in rows:
@@ -695,7 +700,9 @@ class PagedServeEngine(_EngineBase):
                 tok, state, self._keys = self._step(*args)
             counts.update(self._sites["decode"])
             with tracing.span("serve.readback"):
-                toks = np.asarray(jax.device_get(tok)).reshape(-1)
+                toks, routed = jax.device_get((tok, state.stats))
+                toks = np.asarray(toks).reshape(-1)
+            counts.update({k: int(v) for k, v in routed.items()})
             with tracing.span("serve.update"):
                 self.state = state
                 for slot in rows:

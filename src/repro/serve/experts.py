@@ -155,8 +155,7 @@ class ExpertStore:
         self.sites = order
         self.banks = banks
         first = next(iter(banks[order[0]].values()))
-        self.n_experts = (first.n_experts if isinstance(first, st.ExpertBank)
-                          else int(first.shape[first.ndim - 3]))
+        self.n_experts = len(st.expert_entries(first))
         self.capacity = int(capacity)
         self.caches = {s: ExpertCache(self.capacity) for s in order}
         self.counts = {s: np.zeros(self.n_experts, np.float64)
@@ -167,6 +166,8 @@ class ExpertStore:
         b = self.banks[site][kind]
         if isinstance(b, st.ExpertBank):
             return b.entries[e]
+        if isinstance(b, st.CompressedKernel):
+            return b.index(e)
         return jnp.take(b, e, axis=b.ndim - 3)
 
     def _dense_copy(self, site: str, kind: str, e: int):
@@ -233,9 +234,13 @@ class ExpertStore:
 
         def swap(site, kind, w):
             bank = self.banks.get(site, {}).get(kind)
-            if not isinstance(bank, st.ExpertBank):
+            if not isinstance(bank, (st.ExpertBank, st.CompressedKernel)):
                 return w
             cache = self.caches[site]
+            if not cache.keys():
+                return bank
+            # cached experts serve their dense copies, entry by entry
+            bank = st.ExpertBank(st.expert_entries(bank))
             for e in cache.keys():
                 bank = bank.replace_entry(e, cache.get(e)[kind])
             return bank

@@ -22,6 +22,7 @@ ASSIGNED = [
     "zamba2-7b",
     "phi3.5-moe-42b-a6.6b",
     "llama4-scout-17b-a16e",
+    "mellum2-12b-a2.5b",
     "whisper-large-v3",
     "internvl2-2b",
     "mamba2-130m",
@@ -135,6 +136,7 @@ def test_full_config_eval_shape(arch):
         "h2o-danube-1.8b": 1.8e9, "granite-3-8b": 8e9, "gemma2-9b": 9e9,
         "qwen2-7b": 7e9, "zamba2-7b": 7e9,
         "phi3.5-moe-42b-a6.6b": 42e9, "llama4-scout-17b-a16e": 107e9,
+        "mellum2-12b-a2.5b": 12e9,
         "whisper-large-v3": 1.5e9, "internvl2-2b": 2e9,
         "mamba2-130m": 0.13e9,
     }[arch]

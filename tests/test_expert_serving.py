@@ -29,7 +29,7 @@ def setup():
     cfg = ArchConfig(
         name="tiny-moe", family="moe", n_layers=2, d_model=32, n_heads=2,
         n_kv=2, head_dim=16, d_ff=32, vocab=97, n_experts=E, top_k=2,
-        capacity_factor=2.0, moe_group_tokens=8, scan_layers=False,
+        scan_layers=False,
         tied_embeddings=False,
     )
     model = build_model(cfg)
@@ -138,8 +138,8 @@ def test_expert_loads_probe_shape_and_conservation(setup):
     tokens = np.arange(16, dtype=np.int32).reshape(2, 8) % cfg.vocab
     loads = np.asarray(model.expert_loads(params, tokens))
     assert loads.shape == (cfg.n_layers, E)
-    # top-2 routing with capacity slack: every token lands <= 2 experts
-    assert (loads.sum(axis=1) <= 2 * tokens.size).all()
+    # dropless top-2 routing: every token lands on exactly 2 experts
+    assert (loads.sum(axis=1) == 2 * tokens.size).all()
     assert (loads >= 0).all() and loads.sum() > 0
 
 
@@ -182,7 +182,7 @@ def test_ql501_oversize_cache_warns():
     cfg = ArchConfig(
         name="tiny-moe-lint", family="moe", n_layers=2, d_model=32,
         n_heads=2, n_kv=2, head_dim=16, d_ff=32, vocab=97, n_experts=E,
-        top_k=2, capacity_factor=2.0, moe_group_tokens=8,
+        top_k=2,
         scan_layers=False, tied_embeddings=False,
     )
     r = lint(cfg, preset("w4a8_abfp"), experts={"cache_capacity": E})

@@ -30,7 +30,7 @@ def moe_setup():
     cfg = ArchConfig(
         name="tiny-moe", family="moe", n_layers=2, d_model=32, n_heads=2,
         n_kv=2, head_dim=16, d_ff=32, vocab=97, n_experts=E, top_k=2,
-        capacity_factor=2.0, moe_group_tokens=8, scan_layers=False,
+        scan_layers=False,
         tied_embeddings=False,
     )
     model = build_model(cfg)
@@ -137,7 +137,10 @@ def test_store_cached_copy_matches_backing_entry(moe_setup):
     site = store.sites[0]
     for kind in store.banks[site]:
         cached = store.caches[site].get(2)[kind]
-        backing = st.decompress_kernel(store.banks[site][kind].entries[2])
+        # one integer rule for every expert: the bank is one stacked kernel
+        bank = store.banks[site][kind]
+        assert isinstance(bank, st.CompressedKernel)
+        backing = st.decompress_kernel(st.expert_entries(bank)[2])
         np.testing.assert_array_equal(np.asarray(cached),
                                       np.asarray(backing))
 

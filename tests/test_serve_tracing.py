@@ -220,3 +220,45 @@ def test_serve_launcher_reports_span_totals(capsys):
     assert added("serve.decode", "rows_valid") == 3 * 2
     assert added("serve.prefill", "rows_valid") > 0
     assert 0 < out["host_s"] < spans["serve.tick"]["s"]
+
+
+@pytest.mark.parametrize("router", ["zero", "random"])
+def test_engine_counts_routed_expert_rows(router):
+    """A tiny Mellum2 (4 layers, 4 experts, top-2): each ``serve.prefill``
+    and ``serve.decode`` span carries the step's routing summed over the
+    layers.  With a zero router every expert ties and top-k takes experts
+    0 and 1, so by hand: ``expert_rows`` = 2 x 4 x valid rows,
+    ``experts_touched`` = 2 x 4, ``expert_rows_max`` = 4 x valid rows and
+    ``expert_rows_computed`` = 4 x 2 tiles of 32 rows (a chunk holds at
+    most 2 x 8 valid rows).  With a random router, every valid row still
+    takes exactly 2 experts in each layer."""
+    cfg = get_config("mellum2-12b-a2.5b").reduced()
+    assert (cfg.n_layers, cfg.n_experts, cfg.top_k) == (4, 4, 2)
+    model = build_model(cfg)
+    params = unbox(model.init(jax.random.PRNGKey(1)))
+    if router == "zero":
+        ffn = params["blocks"]["ffn"]
+        params["blocks"]["ffn"] = dict(ffn, router=jnp.zeros_like(
+            ffn["router"]))
+    eng = PagedServeEngine(model, params, n_slots=2, max_len=64,
+                           policy=preset("fp32"), page_size=4,
+                           prefill_chunk=8)
+    rng = np.random.RandomState(2)
+    _, spans = _run(eng, [Request(uid=i, prompt=rng.randint(
+        0, cfg.vocab, n).astype(np.int32), max_new_tokens=3)
+        for i, n in enumerate((5, 13, 9))])
+    steps = [s.counts for s in spans
+             if s.name in ("serve.prefill", "serve.decode")]
+    assert steps
+    for c in steps:
+        rows = c["rows_valid"]
+        assert c["expert_rows"] == 2 * 4 * rows
+        if router == "zero":
+            assert c["experts_touched"] == 2 * 4
+            assert c["expert_rows_max"] == 4 * rows
+            assert c["expert_rows_computed"] == 4 * 2 * 32
+        else:
+            assert 2 * 4 <= c["experts_touched"] <= 4 * 4
+            assert 4 * -(-2 * rows // 4) <= c["expert_rows_max"] <= 4 * rows
+            assert c["expert_rows_computed"] % 32 == 0
+            assert c["expert_rows_computed"] >= c["expert_rows"]
