@@ -45,6 +45,20 @@ rows are independent, and the engines ignore dead-row outputs).
 
 GQA never repeats KV in HBM: the block index maps broadcast KV row
 ``(b // H) * KV + (b % H) // G`` to its G query heads.
+
+Paged decode (``paged_decode_attention_quant``; front-end
+``kernels.ops.paged_decode_attention_quant_gqa``): at S == 1 the kernel
+reads the codes where they live, in the layer-stacked page pool
+``(L, P, page_size, KV*D)``.  The grid is one step per row; the page
+table, each row's live context, the layer and the window come in as
+scalar prefetch, and the row's per-(page, head) scales as an SMEM block.
+A ``fori_loop`` walks the row's live page blocks, DMAs each page of a
+block, every KV head in one copy, from ``pool[layer, page]`` while the
+block before computes, and stops at the row's live context (in whole
+read units), so nothing past it is fetched.  Inside a block a
+``fori_loop`` over KV heads contracts each head's lanes of the pages
+with its G query heads, folded into one query tile: each code is read
+from HBM once.
 """
 
 from __future__ import annotations
@@ -305,3 +319,234 @@ def flash_attention_quant(
         scratch_shapes=scratch,
         interpret=interpret,
     )(q, k_codes, v_codes, k_scale, v_scale, q_pos, kv_pos, window)
+
+
+# --------------------------------------------------------------------------
+# Paged decode: one query token per row, KV codes read in place from the
+# layer-stacked page pool.
+# --------------------------------------------------------------------------
+def _paged_decode_kernel(tab_ref, live_ref, qpos_ref, meta_ref, q_ref,
+                         k_hbm, v_hbm, ks_ref, vs_ref, o_ref, kbuf, vbuf,
+                         sems, deq, m_scr, l_scr, acc_scr, *s_scr,
+                         scale: float, n: int, qmax: float, qmin: float,
+                         pages: int, unit: int, n_logical: int):
+    """One row: its live pages, ``pages`` at a time, every KV head.
+
+    Each block's pages (all heads of a page in one DMA) come from
+    ``pool[layer, page]`` into a VMEM buffer while the block before
+    computes; blocks past the row's live context are never visited.  Per
+    block and KV head the arithmetic is the multi-block bodies'
+    (``_kernel_online`` without probs QDQ, ``_kernel_phased`` with it,
+    whose pass 2 reads the scores pass 1 kept instead of recomputing
+    them), and the blocks past the live context are exact no-ops there.
+    """
+    b = pl.program_id(0)
+    layer, window = meta_ref[0], meta_ref[1]
+    live, qp = live_ref[b], qpos_ref[b]
+    ps = kbuf.shape[2]
+    KV, D = q_ref.shape[1], q_ref.shape[3]
+    bk = pages * ps
+    # whole read units (probability groups) of the live context
+    n_read = (live + unit * ps - 1) // (unit * ps) * unit
+    n_blk = (n_read + pages - 1) // pages
+
+    def dma(pool, buf, which, i, j, page):
+        slot = i % 2
+        return pltpu.make_async_copy(pool.at[layer, page], buf.at[slot, j],
+                                     sems.at[which, slot])
+
+    def pages_of(i, op):
+        """``op(j)`` for each page j of block i the row reads."""
+        def body(j, c):
+            op(j)
+            return c
+        jax.lax.fori_loop(0, jnp.minimum(pages, n_read - i * pages), body, 0)
+
+    def fetch(pool, buf, which, i):
+        pages_of(i, lambda j: dma(
+            pool, buf, which, i, j,
+            tab_ref[b * n_logical + i * pages + j]).start())
+
+    def wait(pool, buf, which, i):
+        pages_of(i, lambda j: dma(pool, buf, which, i, j, 0).wait())
+
+    def dequant(buf, sc_ref, i, h):
+        """(bk, D) values of head h in block i: each page's codes x its
+        scale, and zeros for pages the row does not read (their buffer
+        is stale)."""
+        slot = i % 2
+        col = pl.ds(pl.multiple_of(h * D, D), D)
+
+        def body(j, c):
+            page = i * pages + j
+            deq[pl.ds(pl.multiple_of(j * ps, ps), ps), :] = jnp.where(
+                page < n_read,
+                buf[slot, j, :, col].astype(jnp.float32)
+                * sc_ref[0, h, page], 0.0)
+            return c
+        jax.lax.fori_loop(0, pages, body, 0)
+        return deq[...].astype(q_ref.dtype)
+
+    def scores(i, h):
+        """Masked (G, bk) scores of head h's query tile in block i."""
+        s = jax.lax.dot_general(
+            q_ref[0, h], dequant(kbuf, ks_ref, i, h),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale
+        kp = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        ok = (kp < live) & (kp <= qp) & (kp > qp - window)
+        return jnp.where(ok, s, NEG_INF)
+
+    def pv(p, i, h):
+        v = dequant(vbuf, vs_ref, i, h)
+        return jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def prefetch(i, with_v):
+        @pl.when(i < n_blk)
+        def _():
+            fetch(k_hbm, kbuf, 0, i)
+            if with_v:
+                fetch(v_hbm, vbuf, 1, i)
+
+    def heads(op):
+        def body(h, c):
+            op(h)
+            return c
+        jax.lax.fori_loop(0, KV, body, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, M_INIT)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    prefetch(0, True)
+
+    if not n:
+        def online(h, i):
+            s = scores(i, h)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + pv(p, i, h)
+            m_scr[h] = m_new
+
+        def block(i, c):
+            prefetch(i + 1, True)
+            wait(k_hbm, kbuf, 0, i)
+            wait(v_hbm, vbuf, 1, i)
+            heads(lambda h: online(h, i))
+            return c
+
+        jax.lax.fori_loop(0, n_blk, block, 0)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                    ).astype(o_ref.dtype)
+        return
+
+    (s_keep,) = s_scr
+
+    def pass1(h, i):
+        s = scores(i, h)
+        s_keep[i, h] = s
+        m_prev = m_scr[h]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[h] = l_scr[h] * corr + jnp.exp(s - m_new).sum(axis=-1,
+                                                            keepdims=True)
+        m_scr[h] = m_new
+
+    def pass2(h, i):
+        p = jnp.exp(s_keep[i, h] - m_scr[h]) / l_scr[h]
+        p = _probs_qdq(p, n=n, qmax=qmax, qmin=qmin)
+        acc_scr[h] = acc_scr[h] + pv(p, i, h)
+
+    def k_block(i, c):
+        prefetch(i + 1, False)
+        wait(k_hbm, kbuf, 0, i)
+        heads(lambda h: pass1(h, i))
+        return c
+
+    def v_block(i, c):
+        @pl.when(i + 1 < n_blk)
+        def _():
+            fetch(v_hbm, vbuf, 1, i + 1)
+        wait(v_hbm, vbuf, 1, i)
+        heads(lambda h: pass2(h, i))
+        return c
+
+    jax.lax.fori_loop(0, n_blk, k_block, 0)
+    jax.lax.fori_loop(0, n_blk, v_block, 0)
+    o_ref[0] = acc_scr[...].astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("scale", "probs_n", "probs_qmax", "probs_qmin",
+                     "pages", "unit", "interpret"),
+)
+def paged_decode_attention_quant(
+    q: jnp.ndarray,        # (B, KV, G, D) one query token's heads, by KV head
+    k_pool: jnp.ndarray,   # (L, P, ps, KV*D) int8 / fp8-e4m3 codes
+    v_pool: jnp.ndarray,   # (L, P, ps, KV*D)
+    k_scale: jnp.ndarray,  # (B, KV, NL) f32 scale of each row's pages
+    v_scale: jnp.ndarray,  # (B, KV, NL) f32
+    table: jnp.ndarray,    # (B*NL,) int32 physical page of each logical page
+    live: jnp.ndarray,     # (B,) int32 live context tokens of each row
+    q_pos: jnp.ndarray,    # (B,) int32 absolute query positions
+    meta: jnp.ndarray,     # (2,) int32: pool layer, attention window
+    *,
+    scale: float,
+    probs_n: int = 0,
+    probs_qmax: float = 0.0,
+    probs_qmin: float = 0.0,
+    pages: int = 32,       # pages per block (bk = pages * ps positions)
+    unit: int = 1,         # pages per read unit: a row reads whole units
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Decode attention over each row's live pages, read in place; returns
+    (B, KV, G, D).  ``kernels.ops.paged_decode_attention_quant_gqa`` is
+    the front-end that builds the tables from the page table."""
+    B, KV, G, D = q.shape
+    _, _, ps, F = k_pool.shape
+    NL = k_scale.shape[2]
+    bk = pages * ps
+    if NL % pages:
+        raise ValueError(attention_block_message(1, NL * ps, 1, bk))
+    if probs_n and bk % probs_n:
+        raise ValueError(abfp_group_message(bk, probs_n, where="attn probs"))
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=scale, n=probs_n, qmax=probs_qmax,
+        qmin=probs_qmin, pages=pages, unit=unit, n_logical=NL)
+    row = lambda b, *_: (b, 0, 0, 0)
+    sc = pl.BlockSpec((1, KV, NL), lambda b, *_: (b, 0, 0),
+                      memory_space=pltpu.SMEM)
+    scratch = [
+        pltpu.VMEM((2, pages, ps, F), k_pool.dtype),  # K pages, two slots
+        pltpu.VMEM((2, pages, ps, F), v_pool.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((bk, D), jnp.float32),             # one head dequantized
+        pltpu.VMEM((KV, G, 1), jnp.float32),          # running max
+        pltpu.VMEM((KV, G, 1), jnp.float32),          # running denominator
+        pltpu.VMEM((KV, G, D), jnp.float32),          # output accumulator
+    ]
+    if probs_n:  # pass 1's scores, kept for pass 2
+        scratch.append(pltpu.VMEM((NL // pages, KV, G, bk), jnp.float32))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, KV, G, D), row),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                sc, sc,
+            ],
+            out_specs=pl.BlockSpec((1, KV, G, D), row),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        name="flash_attention_quant",
+        interpret=interpret,
+    )(table, live, q_pos, meta, q, k_pool, v_pool, k_scale, v_scale)

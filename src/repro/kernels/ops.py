@@ -12,6 +12,7 @@ halve the preferred block until it does.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -157,6 +158,80 @@ def flash_attention_quant_gqa(qh, k_codes, v_codes, k_scale, v_scale,
         interpret=interpret,
     )
     return o.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+
+
+def probs_read_unit(probs_tq, page_size: int) -> int:
+    """Pages a paged decode row reads at a time: whole ABFP probability
+    groups where probs QDQ is on (``lcm(group, page_size)`` positions),
+    else one page."""
+    n = int(probs_tq.group) if probs_tq is not None else 1
+    return math.lcm(n, page_size) // page_size
+
+
+def paged_decode_attention_quant_gqa(qh, k_pool, v_pool, k_scale, v_scale,
+                                     table, n_ctx, q_pos, layer,
+                                     window=None,
+                                     scale: float | None = None,
+                                     probs_tq=None,
+                                     block_k: int = 512,
+                                     interpret: bool | None = None):
+    """(B, 1, H, D) decode queries over each row's live pages, read in
+    place from the layer-stacked pool.
+
+    ``k_pool``/``v_pool``: (L, P, page_size, KV*D) codes, the last page
+    the trash page; ``k_scale``/``v_scale``: (L, P, KV) f32 per-(page,
+    head) scales; ``table``: (B, NL) physical page of each logical page,
+    -1 unmapped; ``n_ctx``: (B,) tokens visible to each row's query;
+    ``q_pos``: (B,) the query's absolute position; ``layer``: the pool's
+    layer; ``window``: traced sliding window (None = global).
+
+    A row's live context is its first ``n_ctx`` positions, cut at its
+    first unmapped page (the engine maps every row's pages as a prefix);
+    the kernel reads it in whole read units (``probs_read_unit``) and
+    nothing past it, so a row with no live page reads nothing and returns
+    zeros.  The kernel's block of ``block_k`` positions is the
+    multi-block bodies' ``bk``, so with the same block and the same live
+    context the two give the same arithmetic per block.
+    """
+    from repro.kernels import flash_attention_quant as _faq_mod
+
+    interpret = should_interpret() if interpret is None else interpret
+    B, _, H, D = qh.shape
+    _, P, ps, F = k_pool.shape
+    KV = F // D
+    G = H // KV
+    NL = table.shape[1]
+    n = qmax = qmin = 0
+    if probs_tq is not None:
+        n = int(probs_tq.group)
+        qmax, qmin = float(probs_tq.fmt.qmax_pos), float(probs_tq.fmt.qmin)
+    unit = probs_read_unit(probs_tq, ps)
+    pages = unit * max(1, min(block_k // (ps * unit), -(-NL // unit)))
+    NLp = -(-NL // pages) * pages
+    table = table.astype(jnp.int32)
+    mapped = jnp.cumprod(table >= 0, axis=1).sum(axis=1)
+    live = jnp.minimum(jnp.asarray(n_ctx, jnp.int32),
+                       mapped.astype(jnp.int32) * ps)
+    phys = jnp.where(table >= 0, table, P - 1)  # unmapped -> trash page
+    phys = jnp.pad(phys, ((0, 0), (0, NLp - NL)), constant_values=P - 1)
+
+    def row_scales(s):  # (L, P, KV) -> (B, KV, NLp): each row's pages
+        return s[layer, phys].transpose(0, 2, 1)
+
+    if window is None:
+        window = NLp * ps + 1  # > any position delta: global attention
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                      jnp.asarray(window, jnp.int32).reshape(())])
+    Gp = -(-G // 8) * 8  # the query tile's rows: G heads, sublane-padded
+    q = jnp.pad(qh.reshape(B, KV, G, D), ((0, 0), (0, 0), (0, Gp - G),
+                                         (0, 0)))
+    o = _faq_mod.paged_decode_attention_quant(
+        q, k_pool, v_pool, row_scales(k_scale), row_scales(v_scale),
+        phys.reshape(-1), live, jnp.asarray(q_pos, jnp.int32), meta,
+        scale=D**-0.5 if scale is None else scale, probs_n=n,
+        probs_qmax=qmax, probs_qmin=qmin, pages=pages, unit=unit,
+        interpret=interpret)
+    return o[:, :, :G].reshape(B, 1, H, D)
 
 
 def abfp_matmul_fused(x, w, policy: QuantPolicy,

@@ -200,3 +200,33 @@ def flash_attention_quant_ref(qh, k_codes, v_codes, k_scale, v_scale,
         p = abfp_qdq_ref(pp, probs_fmt, probs_n)[..., :T]
     out = jnp.einsum("bkgst,btkd->bskgd", p, vh)
     return out.reshape(B, S, H, D).astype(qh.dtype)
+
+
+def paged_decode_attention_quant_ref(qh, k_pool, v_pool, k_scale, v_scale,
+                                     table, n_ctx, q_pos, layer, *,
+                                     window=None,
+                                     probs_fmt: Format | None = None,
+                                     probs_n: int = 0) -> jnp.ndarray:
+    """Reference for ``kernels.ops.paged_decode_attention_quant_gqa``:
+    gather layer ``layer``'s pages of every row in logical order, then
+    ``flash_attention_quant_ref`` over positions below ``n_ctx`` on the
+    row's leading mapped pages; a row with none returns zeros."""
+    B, _, H, D = qh.shape
+    _, P, ps, F = k_pool.shape
+    KV, NL = F // D, table.shape[1]
+    T = NL * ps
+    phys = jnp.where(table >= 0, table, P - 1)
+    mapped = jnp.cumprod(table >= 0, axis=1).sum(axis=1)
+    live = jnp.minimum(n_ctx, mapped * ps)
+    idx = jnp.arange(T)[None]
+    kv_pos = jnp.where(idx < live[:, None], idx, -1)
+
+    def tokens(s):  # (L, P, KV) -> (B, T, KV): each page's scale per token
+        return jnp.repeat(s[layer][phys], ps, axis=1)
+
+    out = flash_attention_quant_ref(
+        qh, k_pool[layer][phys].reshape(B, T, KV, D),
+        v_pool[layer][phys].reshape(B, T, KV, D), tokens(k_scale),
+        tokens(v_scale), q_pos[:, None], kv_pos, window=window,
+        probs_fmt=probs_fmt, probs_n=probs_n)
+    return jnp.where((live > 0)[:, None, None, None], out, 0.0)

@@ -12,6 +12,11 @@ engine: 4 slots, pages gathered to T = max_len = 512, prefill chunks of
 single-block cut-off (2048).  The stored-codes matmul also runs at the
 benchmark engine's 16 decode rows and 16 x 64 prefill rows.
 
+The paged decode attention kernel reads a layer-stacked int8 pool in
+place at the benchmark engines' geometry: 16 rows, pages of 16, contexts
+spread over 2048 and 32768 positions, 28/4 heads (qwen2-7b) and 32/4
+(Mellum2-12B-A2.5B).
+
 The grouped stored-codes matmul runs at Mellum2-12B-A2.5B's expert widths
 (d_model 2304, expert width 896, 64 experts, top-8) on its benchmark
 engine's rows: 32 slots decoding (256 routed rows in tiles of 32) and a
@@ -26,13 +31,15 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.formats import FP8_E4M3, INT4, INT8
 from repro.core.policy import TensorQuant
 from repro.kernels import ref
 from repro.kernels.abfp_qdq import abfp_qdq
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ops import flash_attention_quant_gqa
+from repro.kernels.ops import (flash_attention_quant_gqa,
+                               paged_decode_attention_quant_gqa)
 from repro.core.abfp import abfp_quantize
 from repro.kernels.quant_matmul import (abfp_matmul, abfp_matmul_int8,
                                         grouped_tile_rows, quant_matmul,
@@ -45,6 +52,8 @@ N_GROUP = 64  # w4a8_abfp's ABFP group
 SLOTS, MAX_LEN, CHUNK = 4, 512, 64
 # Mellum2-12B-A2.5B's experts and its benchmark engine's slots
 EXPERT_D, EXPERT_F, EXPERTS, TOP_K, MOE_SLOTS = 2304, 896, 64, 8, 32
+# the benchmark engines' paged decode: 16 rows, pages of 16, two layers
+PAGED_ROWS, PAGE, PAGED_LAYERS = 16, 16, 2
 
 # Kernels and oracles both run at the TPU's default matmul precision (one
 # bf16 MXU pass over f32 operands, f32 accumulation), so the tolerances
@@ -182,6 +191,49 @@ def _flash_quant_case(body, s, t, code_dtype, probs):
           if body == "online" else (1e-3, _ATTN_WHY)))
 
 
+def _paged_inputs(key, t, heads):
+    """A layer-stacked int8 pool holding 16 rows' pages in scrambled
+    physical order, the rows' contexts spread from 1 token to nearly
+    ``t``; the kernel reads layer 1."""
+    kq, kk, kv, ks, kvs, kp = jax.random.split(key, 6)
+    nl = t // PAGE
+    r = np.arange(PAGED_ROWS)
+    ctx = np.maximum(1, (r + 1) * t // PAGED_ROWS - 7 * r).astype(np.int32)
+    need = -(-ctx // PAGE)
+    n_pages = int(need.sum())
+    first = np.cumsum(need) - need
+    perm = jax.random.permutation(kp, n_pages).astype(jnp.int32)
+    j = jnp.arange(nl, dtype=jnp.int32)[None]
+    table = jnp.where(j < need[:, None],
+                      perm[jnp.minimum(first[:, None] + j, n_pages - 1)], -1)
+    shape = (PAGED_LAYERS, n_pages + 1, PAGE, KV_HEADS * HEAD_DIM)
+    kc, vc = (jax.random.randint(k, shape, -127, 128).astype(jnp.int8)
+              for k in (kk, kv))
+    scales = (PAGED_LAYERS, n_pages + 1, KV_HEADS)
+    k_scale = jax.random.uniform(ks, scales) * 0.05 + 1e-3
+    v_scale = jax.random.uniform(kvs, scales) * 0.05 + 1e-3
+    q = _normal(kq, (PAGED_ROWS, 1, heads, HEAD_DIM))
+    return q, kc, vc, k_scale, v_scale, table, ctx, ctx - 1
+
+
+def _paged_case(t, heads, probs, label):
+    tq = None
+    if probs:
+        from repro.core.policy import preset
+
+        tq = preset("w4a8_abfp").input  # int8 ABFP, n=64, BF16 scales
+    return KernelCase(
+        f"flash_attention_quant[paged decode {label} {heads}/{KV_HEADS} "
+        f"T={t}{' probs-n64' if probs else ''}]",
+        lambda *a: paged_decode_attention_quant_gqa(
+            *a, 1, probs_tq=tq, interpret=False),
+        lambda *a: ref.paged_decode_attention_quant_ref(
+            *a, 1, probs_fmt=None if tq is None else tq.fmt,
+            probs_n=0 if tq is None else tq.group),
+        lambda key: _paged_inputs(key, t, heads),
+        *((1e-2, _PROBS_WHY) if probs else (2.0 ** -7, _ONLINE_WHY)))
+
+
 def kernel_cases() -> list[KernelCase]:
     cases = [
         _qdq_case(INT8, 1 / 127,
@@ -237,4 +289,12 @@ def kernel_cases() -> list[KernelCase]:
             _flash_quant_case("online", CHUNK, 4096, code_dtype, False),
             _flash_quant_case("phased", CHUNK, 4096, code_dtype, True),
         ]
+    # paged decode at the benchmark cells' widths: qwen2-7b (28/4 heads)
+    # at 2048 and 32768 positions, Mellum2-12B-A2.5B (32/4) at 2048
+    cases += [
+        _paged_case(2048, HEADS, True, "qwen2"),
+        _paged_case(2048, HEADS, False, "qwen2"),
+        _paged_case(32768, HEADS, True, "qwen2"),
+        _paged_case(2048, 32, True, "mellum2"),
+    ]
     return cases

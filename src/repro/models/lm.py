@@ -31,7 +31,7 @@ from repro.core.policy import (
     kv_cache_mode,
 )
 from repro.dist import sharding as shd
-from repro.nn.attention import Attention, KVCache, PagedKVCache
+from repro.nn.attention import Attention, KVCache
 from repro.nn.ffn import MLP
 from repro.nn.linear import Dense, Embed
 from repro.nn.moe import COUNT_KEYS, MoE
@@ -673,6 +673,13 @@ class TransformerLM:
             pages=PagedState(cache=cache, table=table), stats=stats,
         )
 
+    def paged_read_unit(self, policy, kv: str, page_size: int):
+        """Pages a decode step reads at a time from each row's live
+        context where it reads them in place (``Attention.paged_step``);
+        None where it gathers the whole page table."""
+        name = "block/attn" if self.cfg.scan_layers else "blocks.0/attn"
+        return self._attention(name).paged_read_unit(policy, kv, page_size)
+
     def paged_step(self, params, tokens, state: DecodeState, *,
                    n_valid, policy=QuantPolicy(), q=None,
                    all_logits: bool = False):
@@ -704,14 +711,17 @@ class TransformerLM:
         lps = self.layer_params()
         valid = jnp.arange(S, dtype=jnp.int32)[None] < n_valid[:, None]
 
-        def body(xc, xs, name="block"):
-            bp, cache, lp = xs
+        # the layers carry the stacked pool whole: each writes its tokens
+        # in place at [layer, page, offset] and reads its pages there
+        def body(carry, xs, name="block"):
+            xc, cache = carry
+            bp, lp, layer = xs
             h = _norm(c).apply(bp["ln1"], xc)
             attn = self._attention(f"{name}/attn")
             h, cache = self._attend(
                 attn, "paged_step", bp["attn"], h, lp, cache=cache,
-                page_table=table, position=pos, n_valid=n_valid,
-                policy=policy,
+                layer=layer, page_table=table, position=pos,
+                n_valid=n_valid, policy=policy,
             )
             if c.post_norms:
                 h = _norm(c).apply(bp["ln1_post"], h)
@@ -720,23 +730,23 @@ class TransformerLM:
             h, metrics = self._ffn(bp["ffn"], h, policy, name, valid=valid)
             if c.post_norms:
                 h = _norm(c).apply(bp["ln2_post"], h)
-            return xc + h, (cache, {} if metrics is None
-                            else metrics["counts"])
+            return (xc + h, cache), ({} if metrics is None
+                                     else metrics["counts"])
 
+        carry = (x, state.pages.cache)
         if c.scan_layers:
-            x, (new_cache, layer_counts) = jax.lax.scan(
-                body, x, (params["blocks"], state.pages.cache, lps))
+            layers = jnp.arange(c.n_layers, dtype=jnp.int32)
+            carry, layer_counts = jax.lax.scan(
+                body, carry, (params["blocks"], lps, layers))
         else:
             outs = []
             for i, bp in enumerate(params["blocks"]):
-                ci = jax.tree_util.tree_map(
-                    lambda a, i=i: a[i], state.pages.cache)
-                ci = PagedKVCache(*ci)
-                x, out = body(x, (bp, ci, self._layer(lps, i)),
-                              name=f"blocks.{i}")
+                carry, out = body(carry, (bp, self._layer(lps, i), i),
+                                  name=f"blocks.{i}")
                 outs.append(out)
-            new_cache, layer_counts = jax.tree_util.tree_map(
+            layer_counts = jax.tree_util.tree_map(
                 lambda *a: jnp.stack(a), *outs)
+        x, new_cache = carry
 
         new_state = DecodeState(
             kv=None, ssm=None, position=pos + n_valid,

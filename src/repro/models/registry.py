@@ -119,6 +119,9 @@ class Model:
                                      n_valid=n_valid, policy=policy,
                                      all_logits=all_logits)
 
+    def paged_read_unit(self, policy, kv: str, page_size: int):
+        return self.inner.paged_read_unit(policy, kv, page_size)
+
 
 def build_model(cfg: ArchConfig):
     if cfg.family == "vit":

@@ -65,7 +65,10 @@ class PagedKVCache(NamedTuple):
     sequence lives in the engine's per-slot page table (threaded through
     ``DecodeState.pages``), not here.  The LAST physical page is the trash
     page: masked/padded writes are routed to it so the jitted scatter
-    stays fixed-shape (it is never gathered unmasked).
+    stays fixed-shape (it is never gathered unmasked).  The serving
+    state stacks every layer's store on a leading axis
+    (``models.lm.PagedState``); ``Attention.paged_step`` takes the stack
+    and its layer's index and updates the stack in place.
 
     Quantized storage (policy.kv_cache 'int8' / 'fp8'): k/v hold codes and
     k_scale/v_scale hold per-(page, kv_head) f32 unit scales — one scale
@@ -829,8 +832,9 @@ class Attention:
         )
 
     def _page_write(self, cache: PagedKVCache, kh, vh, phys_tok, positions,
-                    mode: str) -> PagedKVCache:
-        """Scatter S new tokens per row into the page pool.
+                    mode: str, layer) -> PagedKVCache:
+        """Scatter S new tokens per row into layer ``layer`` of the
+        layer-stacked page pool, in place.
 
         kh/vh: (B, S, n_kv, D) with invalid rows already zeroed.
         ``phys_tok``: (B, S) physical page per token (masked writes
@@ -846,13 +850,13 @@ class Attention:
             requantization drift at all.
         """
         B, S, KV, D = kh.shape
-        ps = cache.k.shape[1]
+        ps = cache.k.shape[2]
         F = KV * D
         if mode == "fp":
             slot = positions % ps
-            new_k = cache.k.at[phys_tok, slot].set(
+            new_k = cache.k.at[layer, phys_tok, slot].set(
                 kh.reshape(B, S, F).astype(cache.k.dtype))
-            new_v = cache.v.at[phys_tok, slot].set(
+            new_v = cache.v.at[layer, phys_tok, slot].set(
                 vh.reshape(B, S, F).astype(cache.v.dtype))
             return PagedKVCache(k=new_k, v=new_v)
         if S == 1:
@@ -861,8 +865,8 @@ class Attention:
             rows = jnp.arange(B)
 
             def upd(store, scale, x4):
-                old = store[phys].reshape(B, ps, KV, D)  # codes
-                s_old = scale[phys]  # (B, n_kv)
+                old = store[layer, phys].reshape(B, ps, KV, D)  # codes
+                s_old = scale[layer, phys]  # (B, n_kv)
                 alpha = jnp.max(jnp.abs(x4[:, 0]), axis=-1)  # (B, n_kv)
                 s_new = jnp.maximum(s_old, _page_unit_scale(alpha, mode))
                 ratio = s_old / s_new  # <= 1; 0 for untouched pages
@@ -876,7 +880,8 @@ class Attention:
                 if mode == "int8":
                     page = jnp.clip(jnp.round(page), -127, 127)
                 page = page.astype(store.dtype).reshape(B, ps, F)
-                return store.at[phys].set(page), scale.at[phys].set(s_new)
+                return (store.at[layer, phys].set(page),
+                        scale.at[layer, phys].set(s_new))
 
             new_k, new_ks = upd(cache.k, cache.k_scale, kh)
             new_v, new_vs = upd(cache.v, cache.v_scale, vh)
@@ -899,18 +904,32 @@ class Attention:
         kc, ks = enc(kh)
         vc, vs = enc(vh)
         return PagedKVCache(
-            k=cache.k.at[phys_pg].set(kc),
-            v=cache.v.at[phys_pg].set(vc),
-            k_scale=cache.k_scale.at[phys_pg].set(ks),
-            v_scale=cache.v_scale.at[phys_pg].set(vs),
+            k=cache.k.at[layer, phys_pg].set(kc),
+            v=cache.v.at[layer, phys_pg].set(vc),
+            k_scale=cache.k_scale.at[layer, phys_pg].set(ks),
+            v_scale=cache.v_scale.at[layer, phys_pg].set(vs),
         )
+
+    def paged_read_unit(self, policy: Policy, mode: str,
+                        page_size: int) -> int | None:
+        """Pages a decode step reads at a time from each row's live
+        context where ``paged_step`` reads them in place (the compressed
+        backend over code pages); None where it gathers the page table."""
+        pol = resolve_policy(policy, self.name)
+        if mode == "fp" or not self.causal or not self._use_compressed(
+                pol, mode=mode, where="the paged KV pool"):
+            return None
+        from repro.kernels.ops import probs_read_unit  # lazy: pallas
+
+        return probs_read_unit(self._attn_probs_tq(pol), page_size)
 
     def paged_step(
         self,
         params: dict,
         x: jnp.ndarray,  # (B, S, d_model): S=1 decode, S=chunk prefill
-        cache: PagedKVCache,
+        cache: PagedKVCache,  # every layer's pool, stacked: (L, P, ...)
         *,
+        layer,  # this layer's index into the stacked pool
         page_table: jnp.ndarray,  # (B, n_logical) physical indices, -1 free
         position: jnp.ndarray,  # (B,) absolute position of x[:, 0]
         n_valid: jnp.ndarray,  # (B,) valid tokens in x (0 masks the row)
@@ -921,11 +940,16 @@ class Attention:
     ) -> tuple[jnp.ndarray, PagedKVCache]:
         """Unified paged write-then-attend over a token chunk.
 
-        Projects S tokens, writes their K/V into the row's pages (invalid
-        tokens — pad rows past ``n_valid`` or rows with no page mapped —
-        go to the trash page), then gathers the row's full page list,
-        rescales quantized pages, zero-masks unwritten positions and runs
-        the reference attention with absolute positions.  Exactness notes:
+        Projects S tokens, writes their K/V into the row's pages of layer
+        ``layer`` of the stacked pool, in place (invalid tokens — pad rows
+        past ``n_valid`` or rows with no page mapped — go to the trash
+        page), then attends.  A decode step (S == 1) over code pages under
+        the compressed backend reads each row's live pages in place
+        (``kernels.ops.paged_decode_attention_quant_gqa``); every other
+        step gathers the row's full page list, rescales quantized pages,
+        zero-masks unwritten positions and runs the reference attention
+        (or the quantized-KV kernel on the gathered codes) with absolute
+        positions.  The pool is returned whole.  Exactness notes:
         gathered-length T = n_logical * page_size differs from the fixed
         engine's max_len, but masked positions are exact zeros and ABFP
         seq-axis groups align from index 0, so requant QDQ over the gather
@@ -936,8 +960,8 @@ class Attention:
         mode = paged_kv_mode(cache)
         B, S, _ = x.shape
         NL = page_table.shape[1]
-        ps = cache.k.shape[1]
-        trash = cache.k.shape[0] - 1
+        ps = cache.k.shape[2]
+        trash = cache.k.shape[1] - 1
         position = jnp.asarray(position, jnp.int32)
         n_valid = jnp.asarray(n_valid, jnp.int32)
         positions = position[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
@@ -961,7 +985,20 @@ class Attention:
         phys_tok = jnp.take_along_axis(page_table, lp, axis=1)
         ok = keep & (phys_tok >= 0) & (positions // ps < NL)
         phys_tok = jnp.where(ok, phys_tok, trash)
-        cache = self._page_write(cache, kh, vh, phys_tok, positions, mode)
+        cache = self._page_write(cache, kh, vh, phys_tok, positions, mode,
+                                 layer)
+        n_ctx = position + n_valid  # tokens visible after this write
+        compressed = self._use_compressed(pol, mode=mode,
+                                          where="the paged KV pool")
+        if compressed and S == 1 and self.causal:
+            from repro.kernels import ops as kops  # lazy: pallas import
+
+            out = kops.paged_decode_attention_quant_gqa(
+                self._quant_q(pol, qh, q), cache.k, cache.v, cache.k_scale,
+                cache.v_scale, page_table, n_ctx, position, layer, window,
+                scale=self._scale(), probs_tq=self._attn_probs_tq(pol),
+            ).astype(jnp.dtype(self.dtype))
+            return self._out(params, out, policy, q), cache
 
         # gather the row's pages in logical order -> contiguous (B, T, ...)
         T = NL * ps
@@ -969,26 +1006,25 @@ class Attention:
         idx = jnp.arange(T, dtype=jnp.int32)[None]  # (1, T) absolute pos
         mapped = jnp.take_along_axis(
             page_table, jnp.broadcast_to(idx // ps, (B, T)), axis=1) >= 0
-        n_ctx = position + n_valid  # tokens visible after this write
         valid = (idx < n_ctx[:, None]) & mapped
         kv_pos = jnp.where(valid, idx, -1)
         if window is None:
             window = jnp.asarray(T + 1, jnp.int32)
-        if self._use_compressed(pol, mode=mode, where="the paged KV pool"):
+        if compressed:
             # gather CODES only — no dequantized dense copy, no zero-mask:
             # invalid/trash positions carry kv_pos = -1, which the kernel
             # turns into probability-exactly-0 (trash never reaches the
             # output), and the page scales broadcast over their tokens.
             with jax.named_scope("gather"):
-                gk = cache.k[phys_tab].reshape(B, T, self.n_kv,
-                                               self.head_dim)
-                gv = cache.v[phys_tab].reshape(B, T, self.n_kv,
-                                               self.head_dim)
+                gk = cache.k[layer, phys_tab].reshape(B, T, self.n_kv,
+                                                      self.head_dim)
+                gv = cache.v[layer, phys_tab].reshape(B, T, self.n_kv,
+                                                      self.head_dim)
                 sk = jnp.broadcast_to(
-                    cache.k_scale[phys_tab][:, :, None, :],
+                    cache.k_scale[layer, phys_tab][:, :, None, :],
                     (B, NL, ps, self.n_kv)).reshape(B, T, self.n_kv)
                 sv = jnp.broadcast_to(
-                    cache.v_scale[phys_tab][:, :, None, :],
+                    cache.v_scale[layer, phys_tab][:, :, None, :],
                     (B, NL, ps, self.n_kv)).reshape(B, T, self.n_kv)
             out = attn_backends()["compressed"].fn(
                 self._quant_q(pol, qh, q), gk, gv, sk, sv,
@@ -998,11 +1034,11 @@ class Attention:
             ).astype(jnp.dtype(self.dtype))
         else:
             with jax.named_scope("gather"):
-                gk = cache.k[phys_tab]  # (B, NL, ps, F)
-                gv = cache.v[phys_tab]
+                gk = cache.k[layer, phys_tab]  # (B, NL, ps, F)
+                gv = cache.v[layer, phys_tab]
                 if mode != "fp":
-                    sk = cache.k_scale[phys_tab][:, :, None, :, None]
-                    sv = cache.v_scale[phys_tab][:, :, None, :, None]
+                    sk = cache.k_scale[layer, phys_tab][:, :, None, :, None]
+                    sv = cache.v_scale[layer, phys_tab][:, :, None, :, None]
                     gk = gk.reshape(B, NL, ps, self.n_kv, self.head_dim)
                     gv = gv.reshape(B, NL, ps, self.n_kv, self.head_dim)
                     gk = (gk.astype(jnp.float32) * sk).astype(
@@ -1018,6 +1054,11 @@ class Attention:
             out = self._reference(qh, gk, gv, positions, kv_pos, window,
                                   policy, q=q,
                                   kv_prequant=kv_on_write or mode != "fp")
+        return self._out(params, out, policy, q), cache
+
+    def _out(self, params, out, policy, q):
+        """The output projection of (B, S, H, D) attention outputs."""
+        B, S = out.shape[:2]
         o_dense = Dense(
             self.n_heads * self.head_dim, self.d_model,
             in_axis="qkv", out_axis="embed",
@@ -1026,4 +1067,4 @@ class Attention:
         )
         y = o_dense.apply(params["o"], out.reshape(B, S, -1), policy,
                           q=None if q is None else q.get("o"))
-        return shd.constrain(y, ("batch", "seq_res", "embed")), cache
+        return shd.constrain(y, ("batch", "seq_res", "embed"))

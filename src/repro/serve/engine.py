@@ -563,9 +563,13 @@ class PagedServeEngine(_EngineBase):
         self._keys = jnp.zeros((n_slots, 2), jnp.uint32)
         self._init_common(n_slots)
 
-        self._step = jax.jit(self._step_fn)
+        # the state is donated: the step updates the page pool in place
+        self._step = jax.jit(self._step_fn, donate_argnums=(2,))
         # compressed matmul sites of each step program, by contraction
         self._sites = {"prefill": {}, "decode": {}}
+        # pages a decode row reads at a time where decode reads each row's
+        # live pages in place; None where it gathers the whole table
+        self._read_unit = model.paged_read_unit(policy, kv, geo.page_size)
 
     # ---------------------------------------------------------- jitted fns
     def _step_fn(self, params, tokens, state, n_valid, keys, temps, topk):
@@ -652,7 +656,8 @@ class PagedServeEngine(_EngineBase):
                         jnp.asarray(self._temps), jnp.asarray(self._topk))
             counts.update(self._step_counts(
                 n_valid, tokens.size,
-                [self._pf_pos[s] + int(n_valid[s]) for s in rows]))
+                [self._pf_pos[s] + int(n_valid[s]) for s in rows],
+                self.table.size))
             with tracing.span("serve.step"):
                 tok, state, self._keys = self._step(*args)
             counts.update(self._sites["prefill"])
@@ -692,10 +697,11 @@ class PagedServeEngine(_EngineBase):
                         jnp.asarray(n_valid), self._keys,
                         jnp.asarray(self._temps), jnp.asarray(self._topk))
             # each row writes its last token after the context it holds
+            context = [len(self.req[s].prompt) + len(self.generated[s])
+                       for s in rows]
             counts.update(self._step_counts(
-                n_valid, self._cur.size,
-                [len(self.req[s].prompt) + len(self.generated[s])
-                 for s in rows]))
+                n_valid, self._cur.size, context,
+                self._decode_pages_read(context)))
             with tracing.span("serve.step"):
                 tok, state, self._keys = self._step(*args)
             counts.update(self._sites["decode"])
@@ -715,20 +721,30 @@ class PagedServeEngine(_EngineBase):
                     elif len(self.generated[slot]) >= req.max_new_tokens:
                         self._evict(slot, "length")
 
+    def _decode_pages_read(self, context: list[int]) -> int:
+        """Pages a decode call reads: each participating row's live pages
+        in whole read units where decode reads them in place, else every
+        page-table entry (the gather)."""
+        u = self._read_unit
+        if u is None:
+            return self.table.size
+        ps = self.geometry.page_size
+        return sum(-(-pages_for(n, ps) // u) * u for n in context)
+
     def _step_counts(self, n_valid: np.ndarray, computed: int,
-                     context: list[int]) -> dict:
+                     context: list[int], pages_read: int) -> dict:
         """Waste counts of one step call, from host state alone.
 
         ``rows_valid`` / ``rows_computed``: token rows that carry a prompt
         or decode token, against the rows the step computes;
         ``pages_live`` / ``pages_read``: pages that hold the participating
-        rows' ``context`` after the call, against the page-table entries
-        the step gathers (``paged_step`` reads every one).
+        rows' ``context`` after the call, against the pages the step reads
+        (a prefill call gathers every page-table entry).
         """
         ps = self.geometry.page_size
         return {"rows_valid": int(n_valid.sum()), "rows_computed": computed,
                 "pages_live": sum(pages_for(n, ps) for n in context),
-                "pages_read": self.table.size}
+                "pages_read": pages_read}
 
     def _evict(self, slot: int, reason: str):
         self._complete(slot, reason)

@@ -181,6 +181,121 @@ def test_trash_slots_never_reach_output():
     assert np.array_equal(out1, out2)
 
 
+# ------------------------------------- paged decode, pages read in place
+def _pool(L, P, ps, KV, D, *, code_dtype="int8", seed=0):
+    """A layer-stacked page pool whose layers hold different codes and
+    scales."""
+    rng = np.random.RandomState(seed)
+    codes = rng.randint(-127, 128, (2, L, P, ps, KV * D)).astype(np.float32)
+    if code_dtype == "int8":
+        kc, vc = (jnp.asarray(c, jnp.int8) for c in codes)
+    else:
+        kc, vc = (jnp.asarray(c / 16.0, jnp.float8_e4m3fn) for c in codes)
+    ks, vs = (jnp.asarray(rng.rand(L, P, KV).astype(np.float32) * 0.05
+                          + 1e-3) for _ in range(2))
+    return kc, vc, ks, vs
+
+
+def _paged_rows(NL, P, seed=0):
+    """Four rows of a decode step, as (table, n_ctx): no live page; a
+    context ending on a page boundary; one token past a 64-group; and
+    scrambled physical pages with the trash page mapped among them."""
+    rng = np.random.RandomState(seed)
+    table = np.full((4, NL), -1, np.int32)
+    perm = rng.permutation(P - 1)
+    table[1, :32] = perm[:32]                 # 128 tokens in pages of 4
+    table[2, :17] = perm[32:49]               # 65 tokens
+    table[3, :38] = perm[49:87]               # 150 tokens, scrambled
+    table[3, 5] = P - 1                       # the trash page, mapped
+    n_ctx = np.asarray([37, 128, 65, 150], np.int32)
+    return jnp.asarray(table), jnp.asarray(n_ctx)
+
+
+def _gather_then_kernel(qh, kc, vc, ks, vs, table, n_ctx, layer, *,
+                        window=None, tq=None):
+    """The gather path: layer ``layer``'s pages gathered in logical order,
+    page scales broadcast over their tokens, the multi-block kernel at
+    blocks of 64 positions.  Each KV head's G query heads, padded to 8,
+    are its query rows, as the paged kernel's query tile holds them, so
+    the two contract the same tiles."""
+    B, NL = table.shape
+    P, ps = kc.shape[1], kc.shape[2]
+    KV, D = ks.shape[-1], qh.shape[-1]
+    G = qh.shape[2] // KV
+    Gp = -(-G // 8) * 8
+    T = NL * ps
+    phys = jnp.where(table >= 0, table, P - 1)
+    idx = jnp.arange(T)[None]
+    mapped = jnp.take_along_axis(
+        table, jnp.broadcast_to(idx // ps, (B, T)), axis=1) >= 0
+    kv_pos = jnp.where((idx < n_ctx[:, None]) & mapped, idx, -1)
+
+    def heads(c):  # (L, P, ps, KV*D) -> (B*KV, T, 1, D)
+        c = c[layer][phys].reshape(B, T, KV, D)
+        return c.transpose(0, 2, 1, 3).reshape(B * KV, T, 1, D)
+
+    def tok(s):  # (L, P, KV) -> (B*KV, T, 1): each page's scale per token
+        s = jnp.repeat(s[layer][phys], ps, axis=1)
+        return s.transpose(0, 2, 1).reshape(B * KV, T, 1)
+
+    q = jnp.pad(qh.reshape(B, KV, G, D), ((0, 0), (0, 0), (0, Gp - G),
+                                         (0, 0))).reshape(B * KV, Gp, 1, D)
+    q_pos = jnp.repeat(jnp.broadcast_to((n_ctx - 1)[:, None], (B, Gp)), KV,
+                       axis=0)
+    o = kops.flash_attention_quant_gqa(
+        q, heads(kc), heads(vc), tok(ks), tok(vs), q_pos,
+        jnp.repeat(kv_pos, KV, axis=0), window, probs_tq=tq, block_k=64,
+        single_block_max=0, interpret=True)
+    return o.reshape(B, KV, Gp, D)[:, :, :G].reshape(B, 1, KV * G, D)
+
+
+@pytest.mark.parametrize("G,probs,window", [
+    (7, False, None), (7, True, None), (8, False, None), (8, True, None),
+    (8, True, 40)])
+def test_paged_decode_matches_gather_then_kernel(G, probs, window):
+    """Layer 1 of a three-layer pool, read in place, against the gather of
+    that layer's pages and the multi-block kernel at the same block of 64
+    positions and the same query tile: bit for bit, since the blocks past
+    a row's live context that only the gather visits are exact no-ops.
+    The row with no live page returns zeros."""
+    KV, D, ps, NL = 2, 16, 4, 48
+    P = 4 * NL + 1
+    kc, vc, ks, vs = _pool(3, P, ps, KV, D, seed=G)
+    table, n_ctx = _paged_rows(NL, P, seed=G)
+    qh = jnp.asarray(np.random.RandomState(G).randn(4, 1, KV * G, D)
+                     .astype(np.float32))
+    tq = preset("w4a8_abfp").input if probs else None
+    win = None if window is None else jnp.asarray(window, jnp.int32)
+    got = np.asarray(kops.paged_decode_attention_quant_gqa(
+        qh, kc, vc, ks, vs, table, n_ctx, n_ctx - 1, 1, win, probs_tq=tq,
+        block_k=64, interpret=True))
+    want = np.asarray(_gather_then_kernel(qh, kc, vc, ks, vs, table, n_ctx,
+                                          1, window=win, tq=tq))
+    assert np.array_equal(got[0], np.zeros_like(got[0]))
+    assert np.array_equal(got[1:], want[1:])
+    # the same two paths at another layer read other codes
+    other = np.asarray(kops.paged_decode_attention_quant_gqa(
+        qh, kc, vc, ks, vs, table, n_ctx, n_ctx - 1, 2, win, probs_tq=tq,
+        block_k=64, interpret=True))
+    assert np.abs(other[1:] - got[1:]).max() > 1e-3
+
+
+def test_paged_decode_reads_fp8_pages():
+    KV, D, ps, NL = 2, 16, 4, 48
+    P = 4 * NL + 1
+    kc, vc, ks, vs = _pool(2, P, ps, KV, D, code_dtype="fp8", seed=5)
+    table, n_ctx = _paged_rows(NL, P, seed=5)
+    qh = jnp.asarray(np.random.RandomState(5).randn(4, 1, 8, D)
+                     .astype(np.float32))
+    got = np.asarray(kops.paged_decode_attention_quant_gqa(
+        qh, kc, vc, ks, vs, table, n_ctx, n_ctx - 1, 1, block_k=64,
+        interpret=True))
+    want = np.asarray(_gather_then_kernel(qh, kc, vc, ks, vs, table, n_ctx,
+                                          1))
+    assert np.isfinite(got).all() and not got[0].any()
+    assert np.array_equal(got[1:], want[1:])
+
+
 # -------------------------------------------------- dispatch + registry
 def test_backend_registry():
     from repro.core.simulate import attn_backends, attention_backend

@@ -361,3 +361,95 @@ def test_paged_geometry_validation(setup):
     with pytest.raises(ValueError, match="cannot admit a maximal request"):
         PagedServeEngine(model, params, n_slots=1, max_len=32,
                          policy=preset("fp32"), page_size=4, n_pages=4)
+
+
+# ------------------------------------- paged decode reading pages in place
+def _compressed(cfg, base, kv):
+    from repro.core.policy import with_attn_backend
+
+    return with_attn_backend(
+        with_kv_cache(preset(base, n_layers=cfg.n_layers), kv), "compressed")
+
+
+def _mixed_requests(vocab, lengths, max_new, seed=11):
+    rng = np.random.RandomState(seed)
+    return [Request(uid=i, prompt=rng.randint(0, vocab, n).astype(np.int32),
+                    max_new_tokens=m)
+            for i, (n, m) in enumerate(zip(lengths, max_new))]
+
+
+def _gather_decode(qh, k_pool, v_pool, k_scale, v_scale, table, n_ctx,
+                   q_pos, layer, window=None, *, scale=None, probs_tq=None):
+    """The paged decode kernel's gather path: the layer's pages in logical
+    order into the quantized-KV kernel, as every step gathered before."""
+    from repro.kernels import ops as kops
+
+    B, NL = table.shape
+    P, ps = k_pool.shape[1], k_pool.shape[2]
+    KV, D = k_scale.shape[-1], qh.shape[-1]
+    T = NL * ps
+    phys = jnp.where(table >= 0, table, P - 1)
+    idx = jnp.arange(T)[None]
+    mapped = jnp.take_along_axis(
+        table, jnp.broadcast_to(idx // ps, (B, T)), axis=1) >= 0
+    kv_pos = jnp.where((idx < n_ctx[:, None]) & mapped, idx, -1)
+    tok = lambda s: jnp.repeat(s[layer][phys], ps, axis=1)  # noqa: E731
+    return kops.flash_attention_quant_gqa(
+        qh, k_pool[layer][phys].reshape(B, T, KV, D),
+        v_pool[layer][phys].reshape(B, T, KV, D), tok(k_scale),
+        tok(v_scale), q_pos[:, None], kv_pos, window, scale=scale,
+        probs_tq=probs_tq)
+
+
+@pytest.mark.parametrize("base,kv", [("w4a8_abfp", "int8"), ("fp32", "fp8")])
+def test_paged_decode_in_place_serves_the_gather_paths_tokens(
+        setup, base, kv, monkeypatch):
+    """Prompts of 3-45 tokens with 2-19 new tokens each, contexts ending
+    on and off page and group boundaries: the engine whose decode reads
+    pages in place serves the tokens of the same engine whose decode
+    gathers every page into the quantized-KV kernel."""
+    from repro.kernels import ops as kops
+
+    cfg, model, params = setup
+    pol = _compressed(cfg, base, kv)
+    lengths, max_new = (3, 45, 16, 29, 8, 33, 5), (19, 2, 12, 7, 16, 4, 9)
+
+    def serve():
+        eng = PagedServeEngine(model, params, n_slots=3, max_len=64,
+                               policy=pol, page_size=4, prefill_chunk=8,
+                               kv=kv)
+        for r in _mixed_requests(cfg.vocab, lengths, max_new):
+            eng.submit(r)
+        return {c.uid: c.tokens for c in eng.run_until_done()}
+
+    in_place = serve()
+    monkeypatch.setattr(kops, "paged_decode_attention_quant_gqa",
+                        _gather_decode)
+    assert in_place == serve()
+    assert [len(in_place[i]) for i in range(len(lengths))] == list(max_new)
+
+
+def test_paged_decode_compiles_nothing_after_the_first_decode_tick(setup):
+    """Rows of very different live lengths (4 to 120 tokens of 128) decode
+    across many ticks, through admissions and evictions, and no program
+    compiles after the first tick that decodes: the live bound is a
+    traced value, never a shape."""
+    cfg, model, params = setup
+    eng = PagedServeEngine(model, params, n_slots=3, max_len=128,
+                           policy=_compressed(cfg, "w4a8_abfp", "int8"),
+                           page_size=4, prefill_chunk=8, kv="int8")
+    for r in _mixed_requests(cfg.vocab, (3, 90, 20, 64, 9, 110, 40),
+                             (30, 30, 6, 25, 40, 10, 12), seed=4):
+        eng.submit(r)
+    while not eng.active.any():
+        eng.tick()
+    eng.tick()  # the first tick that decodes
+    compiles, armed = [], [True]
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda ev, d, **kw: compiles.append(ev) if armed[0] and ev
+        == "/jax/core/compile/backend_compile_duration" else None)
+    try:
+        done = eng.run_until_done()
+    finally:
+        armed[0] = False
+    assert len(done) == 7 and compiles == []

@@ -152,6 +152,39 @@ def test_pages_live_hand_counted_for_two_requests(setup):
                    ("serve.prefill", 2, 3), ("serve.decode", 2, 5)]
 
 
+@pytest.mark.parametrize("base,unit", [("fp32", 1), ("w4a8_abfp", 16)])
+def test_pages_read_hand_counted_where_decode_reads_in_place(setup, base,
+                                                            unit):
+    """The requests of the test above on int8 pages with the compressed
+    attention backend, whose decode reads each row's live pages in place.
+    A prefill call still gathers the whole table, 2 x 16 pages.  A decode
+    call reads each row's live pages in whole read units: one page where
+    the probabilities are not quantized (contexts 6, then 7 and 11: 2,
+    then 2 + 3 pages), 16 pages of 4 (one ABFP group of 64 positions)
+    under w4a8_abfp (16, then 16 + 16)."""
+    from repro.core.policy import with_attn_backend, with_kv_cache
+
+    cfg, model, params = setup
+    pol = with_attn_backend(with_kv_cache(
+        preset(base, n_layers=cfg.n_layers), "int8"), "compressed")
+    eng = PagedServeEngine(model, params, n_slots=2, max_len=64,
+                           policy=pol, page_size=4, prefill_chunk=8,
+                           kv="int8")
+    _, spans = _run(eng, [
+        Request(uid=0, prompt=np.arange(1, 6, dtype=np.int32),
+                max_new_tokens=3),
+        Request(uid=1, prompt=np.arange(7, 17, dtype=np.int32),
+                max_new_tokens=2)])
+    got = [(s.name, s.counts["pages_live"], s.counts["pages_read"])
+           for s in spans if s.name in ("serve.prefill", "serve.decode")]
+    by_rows = {1: [2], 2: [2, 3]}
+    assert got == [
+        ("serve.prefill", 4, 32),
+        ("serve.decode", 2, sum(-(-p // unit) * unit for p in by_rows[1])),
+        ("serve.prefill", 3, 32),
+        ("serve.decode", 5, sum(-(-p // unit) * unit for p in by_rows[2]))]
+
+
 def test_admission_blocked_on_pages_is_counted(setup):
     cfg, model, params = setup
     # 6 pages of 4: the first request reserves 5, so the second waits
